@@ -124,7 +124,7 @@ serve-paged:
 serve-auto:
 	$(PY) -m repro.launch.serve --arch granite-8b --smoke --exec aimc \
 	    --placement auto:2 --tile-rows 64 --adc-alpha 0.5 --requests 4 \
-	    --prompt-len 8 --gen 6 --seed 89 --placement-verify
+	    --prompt-len 8 --gen 6 --seed 1 --placement-verify
 
 # Multi-tenant serving smoke: two models resident in one process (granite
 # co-programmed on the shared TilePool, xlstm digital), interleaved

@@ -20,9 +20,9 @@ from repro.core.aimc import AimcConfig
 from repro.core.noise import NoiseModel
 from repro.models import paper_nets
 
-NOISY = AimcConfig(tile_rows=512, impl="ref",
+NOISY = AimcConfig(tile_rows=512,
                    noise=NoiseModel(sigma_read=0.003))
-CLEAN = AimcConfig(tile_rows=512, impl="ref")
+CLEAN = AimcConfig(tile_rows=512)
 
 
 def snr_db(ref, test) -> float:

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import Check, fmt_t, table
-from repro.compat import cost_analysis
 from repro.core.aimc import AimcConfig, program_linear
 from repro.core.costmodel import HIGH_POWER, evaluate, speedup
 from repro.core.coupling import loose_forward, tight_forward
@@ -60,7 +59,7 @@ def run(verbose: bool = True) -> dict:
     # follows from its BlockSpecs; the staged path adds a write+read round
     # trip per analog-domain intermediate.
     from repro.core.coupling import hbm_bytes_loose, hbm_bytes_tight
-    cfg = AimcConfig(tile_rows=512, impl="ref")
+    cfg = AimcConfig(tile_rows=512)
     wmat = jax.random.normal(jax.random.PRNGKey(0), (1024, 1024)) * 0.02
     state = program_linear(wmat, cfg)
     # numerics cross-check on this container
@@ -94,7 +93,7 @@ def run(verbose: bool = True) -> dict:
             y = jitted(xv)
         jax.block_until_ready(y)
         meas[name] = (time.perf_counter() - t0) / 10, \
-            cost_analysis(compiled).get("bytes accessed", 0.0)
+            compiled.cost_analysis().get("bytes accessed", 0.0)
     t_ratio = meas["loose"][0] / meas["tight"][0]
     bytes_distinct = meas["tight"][1] != meas["loose"][1]
     if verbose:

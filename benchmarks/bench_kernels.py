@@ -110,7 +110,7 @@ def _noise_operand_absent(state, xf, s_x, cfg, sigma) -> bool:
 
 
 def run(verbose: bool = True) -> dict:
-    cfg = AimcConfig(tile_rows=256, impl="ref", noise=NOISY)
+    cfg = AimcConfig(tile_rows=256, noise=NOISY)
     sigma = read_sigma_lsb(cfg.tile_rows, NOISY)
     seed = jnp.uint32(0xA11CE)
 
@@ -125,9 +125,8 @@ def run(verbose: bool = True) -> dict:
         xf = jnp.pad(x, ((0, 0), (0, kb * m - k)))
         s_x = sym_scale(xf).reshape(1, 1)
 
-        y_ref = ops.aimc_matmul_v2(xf, st.w_q, st.s_w, s_x, seed,
-                                   adc_step=cfg.adc_step, sigma=sigma,
-                                   impl="ref")
+        y_ref = ref.aimc_matmul_ref_v2(xf, st.w_q, st.s_w, s_x, seed,
+                                       adc_step=cfg.adc_step, sigma=sigma)
         t0 = time.perf_counter()
         y_pal = ops.aimc_matmul_v2(xf, st.w_q, st.s_w, s_x, seed,
                                    adc_step=cfg.adc_step, sigma=sigma,

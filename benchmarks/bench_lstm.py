@@ -104,7 +104,7 @@ def run_wallclock(nh: int = 750, steps: int = 16, batch: int = 8,
                                  program_linear)
     from repro.models.paper_nets import _lstm_cell_math, lstm_init
 
-    cfg = AimcConfig(tile_rows=512, impl="ref")
+    cfg = AimcConfig(tile_rows=512)
     params = lstm_init(jax.random.PRNGKey(0), nh)
     w_cell = jnp.concatenate([params["w_f"], params["w_i"], params["w_g"],
                               params["w_o"]], axis=1)

@@ -95,7 +95,7 @@ def run_wallclock(batch: int = 8, iters: int = 30, verbose: bool = True) -> dict
                                  program_linear)
     from repro.models.paper_nets import mlp_init
 
-    cfg = AimcConfig(tile_rows=512, impl="ref")
+    cfg = AimcConfig(tile_rows=512)
     params = mlp_init(jax.random.PRNGKey(0), 1024)
     x = jax.random.normal(jax.random.PRNGKey(1), (batch, 1024))
 

@@ -64,7 +64,7 @@ def run(verbose: bool = True) -> dict:
     cfg_model = spec.smoke_cfg
     model = spec.model_module()
     params = model.init(jax.random.PRNGKey(0), cfg_model)
-    acfg = AimcConfig(impl="ref", adc_alpha=0.5, tile_rows=64)
+    acfg = AimcConfig(adc_alpha=0.5, tile_rows=64)
 
     # ---- gate (a): per-layer sums == evaluate() on the split workload ----
     res = plan_placement(params, MappingPlan(), acfg, tiles_per_context=None,

@@ -115,7 +115,7 @@ def _setup(arch: str, programmed: bool, n_contexts: int = 1):
         # [1, P] engine prefill and a [B, P] static prefill would quantize
         # the same request differently — with a fixed scale the programmed
         # path is batch-size independent and engine == static bit-for-bit
-        aimc_cfg = AimcConfig(impl="ref", input_scale=0.1)
+        aimc_cfg = AimcConfig(input_scale=0.1)
         exe = Execution(mode="aimc", aimc=aimc_cfg, compute_dtype="float32",
                         programmed=True)
         program = program_model(params, MappingPlan(n_contexts=n_contexts),
@@ -603,7 +603,7 @@ def _bench_drift_case(arch: str, verbose: bool) -> dict:
     cfg = spec.smoke_cfg
     model = spec.model_module()
     params = model.init(jax.random.PRNGKey(0), cfg)
-    aimc_cfg = AimcConfig(impl="ref", input_scale=0.1)
+    aimc_cfg = AimcConfig(input_scale=0.1)
     exe = Execution(mode="aimc", aimc=aimc_cfg, compute_dtype="float32",
                     programmed=True)
     plan = MappingPlan(n_contexts=2)

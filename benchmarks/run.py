@@ -39,6 +39,7 @@ from benchmarks import (bench_accuracy, bench_cnn, bench_coupling,
                         bench_kernels, bench_lstm, bench_mlp,
                         bench_pipeline, bench_placement, bench_roofline,
                         bench_server, bench_serving)
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     ("mlp", "MLP (paper Fig. 7/8)", bench_mlp),
@@ -129,6 +130,7 @@ def main(argv=None) -> None:
     if args.mesh:
         from repro.launch.serve import force_host_device_count
         force_host_device_count(args.mesh)
+    enable_compile_cache()
 
     all_checks = []
     report = {"modules": {}}

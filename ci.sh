@@ -87,7 +87,7 @@ if [[ "${1:-}" == "--fast" ]]; then
     # books reconcile, and nothing recompiled after warmup (DESIGN.md §16)
     python -m repro.launch.serve --arch granite-8b --smoke --exec aimc \
         --placement auto:2 --tile-rows 64 --adc-alpha 0.5 --requests 4 \
-        --prompt-len 8 --gen 6 --seed 89 --placement-verify
+        --prompt-len 8 --gen 6 --seed 1 --placement-verify
     echo "== perf-smoke: bench_kernels (interpret mode) =="
     exec python -m benchmarks.bench_kernels --json BENCH_kernels.json
 fi

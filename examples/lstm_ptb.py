@@ -65,7 +65,7 @@ for i in range(60):
 print(f"  final    char NLL {float(l):.3f}")
 
 # ---- inference: digital vs AIMC (gates side by side, §VIII-D) ---------------
-cfg = AimcConfig(tile_rows=NH + VOCAB + 50, tile_cols=4 * NH + 64, impl="ref")
+cfg = AimcConfig(tile_rows=NH + VOCAB + 50, tile_cols=4 * NH + 64)
 y_dig = paper_nets.lstm_forward_digital(params, xs[:, :4], NH)
 y_ana, ctx = paper_nets.lstm_forward_aimc(params, xs[:, :4], NH, cfg,
                                           jax.random.fold_in(KEY, 2))

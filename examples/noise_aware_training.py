@@ -27,11 +27,11 @@ _NOISE = NoiseModel(sigma_prog_min=0.08, sigma_prog_max=0.20,
                     sigma_read=0.03, drift_t_ratio=1e3)
 # training injects the programming-type noise at the deployment level but a
 # gentler read noise — the recipe in Joshi et al. [16]
-TRAIN_CFG = AimcConfig(tile_rows=256, impl="ref",
+TRAIN_CFG = AimcConfig(tile_rows=256,
                        noise=NoiseModel(sigma_prog_min=0.08,
                                         sigma_prog_max=0.20,
                                         sigma_read=0.01))
-EVAL_CFG = AimcConfig(tile_rows=256, impl="ref", noise=_NOISE)
+EVAL_CFG = AimcConfig(tile_rows=256, noise=_NOISE)
 
 
 W_TRUE = jax.random.normal(jax.random.fold_in(KEY, 99), (N_IN, N_CLS))

@@ -62,7 +62,7 @@ spec = get_arch("llama3.2-3b")
 model = spec.model_module()
 params = model.init(key, spec.smoke_cfg)
 toks = jnp.ones((2, 16), jnp.int32)
-exe = Execution(mode="aimc", aimc=AimcConfig(impl="ref"),
+exe = Execution(mode="aimc", aimc=AimcConfig(),
                 compute_dtype="float32")
 logits, _ = model.forward(params, toks, spec.smoke_cfg, exe,
                           jax.random.PRNGKey(2))
@@ -77,7 +77,7 @@ print(f"llama3.2-3b (smoke cfg) forward through simulated crossbars: "
 # apply-only (CM_INITIALIZE leaves the hot path entirely).
 from repro.core.program import MappingPlan, program_model
 
-serve_cfg = AimcConfig(impl="ref")
+serve_cfg = AimcConfig()
 program = program_model(params, MappingPlan(), serve_cfg,
                         jax.random.PRNGKey(3))
 print(program.summary())

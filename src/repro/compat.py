@@ -1,61 +1,20 @@
-"""Version portability shims for the jax API surface we depend on.
+"""The one mesh query the installed JAX (0.9) does not spell publicly.
 
-The framework targets the modern jax API (``jax.set_mesh``,
-``jax.sharding.get_abstract_mesh``, ``jax.shard_map`` with ``check_vma``);
-the pinned toolchain ships jax 0.4.37, where those spellings either do not
-exist yet or live under different names. Everything version-sensitive is
-funneled through this module so call sites stay on the modern spelling:
-
-  * ``use_mesh(mesh)``      — context manager activating a mesh for both
-    ``with_sharding_constraint`` and ``shard_map`` (``jax.set_mesh`` on new
-    jax; the ``Mesh`` context manager — thread_resources — on 0.4.x).
-  * ``current_mesh()``      — the active concrete mesh or ``None``; works
-    inside and outside jit on both API generations.
-  * ``shard_map(...)``      — ``jax.shard_map`` / ``jax.experimental``
-    dispatch, translating ``check_vma`` <-> ``check_rep``.
+Call sites use the installed API directly: ``jax.set_mesh`` activates a
+mesh, ``jax.shard_map`` maps over it, ``Compiled.cost_analysis()`` returns
+a dict. What remains here is ``current_mesh()``: the active *concrete*
+mesh, inside and outside ``jit``. ``jax.sharding.get_mesh`` refuses to run
+under a trace and ``get_abstract_mesh`` carries no devices, but
+``shard_map`` callers (``models/moe.py``, ``core/schedule.py``) and the
+engine's buffer commit (``ServeEngine._commit_ambient``) need the devices.
 """
 
 from __future__ import annotations
 
-import jax
-
-
-def use_mesh(mesh):
-    """Activate `mesh` for the duration of a ``with`` block."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    # jax 0.4.x: Mesh is itself a context manager feeding thread_resources.
-    return mesh
+from jax._src.mesh import get_concrete_mesh
 
 
 def current_mesh():
-    """The active mesh, or None when no mesh context is active."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        m = get_abstract()
-        return None if m is None or m.empty else m
-    from jax.interpreters import pxla
-    m = pxla.thread_resources.env.physical_mesh
-    return None if m.empty else m
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict (0.4.x wraps it in a
-    one-element-per-device list; newer jax returns the dict directly)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the ``check_vma``/``check_rep`` rename papered
-    over (the flag's semantics are identical for our uses)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+    """The mesh set by ``jax.set_mesh``, or None when none is active."""
+    m = get_concrete_mesh()
+    return None if m is None or m.empty else m

@@ -43,7 +43,9 @@ class AimcConfig:
     adc_alpha: float = 1.0         # ADC full-scale factor (quant.adc_step_lsb)
     input_scale: float = 0.0       # 0.0 = dynamic (max-abs); >0 = fixed scale
     noise: noise_lib.NoiseModel = noise_lib.DISABLED
-    impl: str = "ref"              # ref | pallas_interpret | pallas_tpu
+    # auto = compiled Pallas kernel on a TPU backend, jnp oracle elsewhere
+    # (kernels/ops.resolve_impl); ref | pallas_interpret | pallas_tpu pin one
+    impl: str = "auto"
     out_dtype: str = "float32"
     # kernel v2: apply bias + activation inside the kernel's last row-block
     # step (False = exact unfused fallback, same math as separate ops).

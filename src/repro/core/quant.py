@@ -29,9 +29,12 @@ QMIN = -127
 
 
 def sym_scale(x: jnp.ndarray, axis=None, eps: float = 1e-12) -> jnp.ndarray:
-    """Symmetric max-abs quantization scale so x/scale fits in [-127, 127]."""
+    """Symmetric max-abs quantization scale so x/scale fits in [-127, 127].
+
+    Written as the multiply by 1/127 that XLA turns ``amax / 127`` into
+    under ``jit``: eager and compiled calls then agree on the scale."""
     amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
-    return jnp.maximum(amax, eps) / QMAX
+    return jnp.maximum(amax, eps) * (1.0 / QMAX)
 
 
 def quantize(x: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:

@@ -323,7 +323,6 @@ class CoreSchedule:
         exactly the paper's case-4 dataflow."""
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
         shs = self._by_name[name]
         if self._inv_perm[name] is None:
             raise ValueError(f"matrix {name!r} has a single full shard; "
@@ -348,8 +347,9 @@ class CoreSchedule:
 
             return jax.vmap(one)(wq_l, sw_l)
 
-        parts = shard_map(shard_fn, mesh, in_specs=(P(axis), P(axis), P()),
-                          out_specs=P(axis), check_vma=False)(w_q, s_w, x)
+        parts = jax.shard_map(shard_fn, mesh=mesh,
+                              in_specs=(P(axis), P(axis), P()),
+                              out_specs=P(axis), check_vma=False)(w_q, s_w, x)
         y = jnp.concatenate(list(parts), axis=-1)
         return y[..., self._inv_perm[name]]
 

@@ -6,6 +6,7 @@
   cprng           — counter-based Gaussian PRNG shared by kernel and oracle
                     (bit-identical noise from a scalar seed, no HBM tensor)
   flash_attention — chunked online-softmax attention (O(seq) memory)
-  ops             — jit'd dispatch wrappers (impl = ref | pallas_interpret | pallas_tpu)
+  ops             — jit'd dispatch wrappers (impl = auto | ref | pallas_interpret | pallas_tpu;
+                    auto = the kernel on a TPU, the oracle elsewhere)
   ref             — pure-jnp oracles (bit-identical math, the AIMClib "checker")
 """

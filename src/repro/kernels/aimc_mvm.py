@@ -40,8 +40,11 @@ VMEM working set per step: x block bB*M f32 + weight panel M*bN int8 + out
 block — v2 carries no noise block — sized well under 16 MB for the default
 (bB=128, M=512, bN=512).
 
-Validated against kernels/ref.py in interpret mode (CPU container); on real
-TPU hardware drop interpret=True.
+Block shapes keep Mosaic's tiling rule: the last two dims of every block
+are (8, 128)-divisible or span the array. Per-column rows (`s_w`, `bias`)
+therefore get a unit axis (`[KB, 1, Np]`, block `(1, 1, bN)`) in the
+wrappers below. The kernels compile by default; tests run them with
+`interpret=True` against kernels/ref.py.
 """
 
 from __future__ import annotations
@@ -64,6 +67,10 @@ from repro.kernels import cprng
 from repro.kernels.ref import EPILOGUE_FNS as _ACT_FNS
 
 EPILOGUES = ("none", "relu", "sigmoid", "tanh")
+# The int8 MAC is exact at any precision, and Mosaic lowers an int8 dot only
+# at DEFAULT: pinned, so a caller's float32 matmul precision
+# (`Execution.matmul_precision`) never reaches it.
+_INT8_MAC = jax.lax.Precision.DEFAULT
 NOISE_SOURCES = ("counter", "hw")
 
 
@@ -92,6 +99,7 @@ def _aimc_mvm_kernel(x_ref, w_ref, sw_ref, sx_ref, noise_ref, o_ref, *, adc_step
         x_q,
         w_ref[0],
         (((1,), (0,)), ((), ())),
+        precision=_INT8_MAC,
         preferred_element_type=jnp.int32,
     ).astype(jnp.float32)
     acc = acc + noise_ref[0]
@@ -100,7 +108,7 @@ def _aimc_mvm_kernel(x_ref, w_ref, sw_ref, sx_ref, noise_ref, o_ref, *, adc_step
     codes = jnp.clip(jnp.round(acc / adc_step), QMIN, QMAX)
 
     # ---- digital: dequant + per-row-block accumulate (CM_DEQUEUE + cast) ----
-    contrib = codes * (sw_ref[0] * (adc_step * s_x))[None, :]
+    contrib = codes * (sw_ref[0] * (adc_step * s_x))
 
     @pl.when(k == 0)
     def _init():
@@ -120,7 +128,7 @@ def aimc_matmul_pallas(
     adc_step: float,
     block_b: int = 128,
     block_n: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     kb, m, np_ = w_q.shape
     b = x.shape[0]
@@ -136,14 +144,14 @@ def aimc_matmul_pallas(
         in_specs=[
             pl.BlockSpec((bb, m), lambda i, j, k: (i, k)),          # x
             pl.BlockSpec((1, m, bn), lambda i, j, k: (k, 0, j)),    # w_q (stationary panel)
-            pl.BlockSpec((1, bn), lambda i, j, k: (k, j)),          # s_w
+            pl.BlockSpec((1, 1, bn), lambda i, j, k: (k, 0, j)),    # s_w
             pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),           # s_x
             pl.BlockSpec((1, bb, bn), lambda i, j, k: (k, i, j)),   # read noise
         ],
         out_specs=pl.BlockSpec((bb, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, np_), jnp.float32),
         interpret=interpret,
-    )(x.astype(jnp.float32), w_q, s_w, s_x, read_noise)
+    )(x.astype(jnp.float32), w_q, s_w.reshape(kb, 1, np_), s_x, read_noise)
 
 
 # ---------------------------------------------------------------------------
@@ -151,21 +159,26 @@ def aimc_matmul_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _in_kernel_noise(seed, k, i, j, grid_dims, bb: int, bn: int,
+def _in_kernel_noise(seed, off_ref, k, i, j, grid_dims, bb: int, bn: int,
                      b_total: int, n_total: int, noise_source: str):
-    """One [bb, bn] tile of read noise, generated in VMEM (never from HBM)."""
+    """One [bb, bn] tile of read noise, generated in VMEM (never from HBM).
+
+    `off_ref` holds (row0, col0, shard) of this call within a sharded MVM
+    (`offsets` of the front doors): counters address global rows and
+    columns, and each shard seeds its own range of hardware PRNG cells."""
     if noise_source == "counter":
-        return cprng.noise_tile(seed, k, i * bb, j * bn, bb, bn,
-                                b_total, n_total)
+        return cprng.noise_tile(seed, k, off_ref[0] + i * bb,
+                                off_ref[1] + j * bn, bb, bn, b_total, n_total)
     # hardware PRNG (compiled TPU only): a distinct stream per grid cell.
-    cell = jnp.int32(0)
+    cell, n_cells = jnp.int32(0), 1
     for pid, extent in grid_dims:
         cell = cell * jnp.int32(extent) + pid
-    pltpu.prng_seed(seed.astype(jnp.int32) + cell)
+        n_cells *= extent
+    pltpu.prng_seed(seed.astype(jnp.int32) + off_ref[2] * n_cells + cell)
     h1 = pltpu.bitcast(pltpu.prng_random_bits((bb, bn)), jnp.uint32)
     h2 = pltpu.bitcast(pltpu.prng_random_bits((bb, bn)), jnp.uint32)
-    u1 = ((h1 >> 8).astype(jnp.float32) + 1.0) * jnp.float32(2 ** -24)
-    u2 = (h2 >> 8).astype(jnp.float32) * jnp.float32(2 ** -24)
+    u1 = (cprng.to_f32(h1 >> 8) + 1.0) * jnp.float32(2 ** -24)
+    u2 = cprng.to_f32(h2 >> 8) * jnp.float32(2 ** -24)
     return jnp.sqrt(-2.0 * jnp.log(u1)) * jnp.cos(
         jnp.float32(6.283185307179586) * u2)
 
@@ -174,16 +187,17 @@ def _mac_adc_contrib(x_blk, w_panel, sw_row, s_x, noise, adc_step: float):
     """DAC -> int8 MAC -> (+noise) -> ADC -> dequant: one row-block contrib."""
     x_q = jnp.clip(jnp.round(x_blk / s_x), QMIN, QMAX).astype(jnp.int8)
     acc = jax.lax.dot_general(
-        x_q, w_panel, (((1,), (0,)), ((), ())),
+        x_q, w_panel, (((1,), (0,)), ((), ())), precision=_INT8_MAC,
         preferred_element_type=jnp.int32,
     ).astype(jnp.float32)
     if noise is not None:
         acc = acc + noise
     codes = jnp.clip(jnp.round(acc / adc_step), QMIN, QMAX)
-    return codes * (sw_row * (adc_step * s_x))[None, :]
+    return codes * (sw_row * (adc_step * s_x))
 
 
-def _aimc_mvm_kernel_v2(seed_ref, x_ref, w_ref, sw_ref, sx_ref, *rest, adc_step: float,
+def _aimc_mvm_kernel_v2(seed_ref, off_ref, x_ref, w_ref, sw_ref, sx_ref, *rest,
+                        adc_step: float,
                         sigma: float, activation: str, has_bias: bool,
                         grid_bij: tuple[int, int, int], b_total: int,
                         n_total: int, noise_source: str):
@@ -196,8 +210,8 @@ def _aimc_mvm_kernel_v2(seed_ref, x_ref, w_ref, sw_ref, sx_ref, *rest, adc_step:
     noise = None
     if sigma > 0.0:
         grid_dims = ((i, grid_bij[0]), (j, grid_bij[1]), (k, kb))
-        noise = sigma * _in_kernel_noise(seed_ref[0], k, i, j, grid_dims,
-                                         bb, bn, b_total, n_total,
+        noise = sigma * _in_kernel_noise(seed_ref[0], off_ref, k, i, j,
+                                         grid_dims, bb, bn, b_total, n_total,
                                          noise_source)
     s_x = sx_ref[0, 0]
     contrib = _mac_adc_contrib(x_ref[...], w_ref[0], sw_ref[0], s_x, noise,
@@ -223,7 +237,7 @@ def _aimc_mvm_kernel_v2(seed_ref, x_ref, w_ref, sw_ref, sx_ref, *rest, adc_step:
 @functools.partial(
     jax.jit,
     static_argnames=("adc_step", "sigma", "activation", "block_b", "block_n",
-                     "noise_source", "interpret", "b_logical"),
+                     "noise_source", "interpret", "b_logical", "n_logical"),
 )
 def aimc_matmul_pallas_v2(
     x, w_q, s_w, s_x, seed=None, bias=None, *,
@@ -233,8 +247,10 @@ def aimc_matmul_pallas_v2(
     block_b: int = 128,
     block_n: int = 512,
     noise_source: str = "counter",
-    interpret: bool = True,
+    interpret: bool = False,
     b_logical: int | None = None,
+    offsets=None,
+    n_logical: int | None = None,
 ):
     """Kernel v2 front door (block-aligned shapes; `ops.aimc_matmul_v2` pads).
 
@@ -243,6 +259,10 @@ def aimc_matmul_pallas_v2(
     out entirely). `bias` is a `[1, Np]` f32 row added on the last row-block
     step; `activation` one of `EPILOGUES`. `b_logical` is the pre-padding
     batch, addressing noise counters so padded rows never shift real draws.
+    A shard of a larger MVM passes that MVM's `b_logical`/`n_logical` and
+    its int32 `offsets` (first row, first column, shard index) so its noise
+    is the slice the whole call would draw; the default is an unsharded
+    call at (0, 0, 0).
     """
     _check_epilogue(activation)
     if noise_source not in NOISE_SOURCES:
@@ -259,18 +279,20 @@ def aimc_matmul_pallas_v2(
         seed = jnp.zeros((1,), jnp.uint32)
     else:
         seed = jnp.asarray(seed).reshape((1,)).astype(jnp.uint32)
+    offsets = (jnp.zeros((3,), jnp.int32) if offsets is None
+               else jnp.asarray(offsets, jnp.int32).reshape((3,)))
 
     grid = (b // bb, np_ // bn, kb)
     has_bias = bias is not None
     in_specs = [
-        pl.BlockSpec((bb, m), lambda i, j, k, s: (i, k)),          # x
-        pl.BlockSpec((1, m, bn), lambda i, j, k, s: (k, 0, j)),    # w_q panel
-        pl.BlockSpec((1, bn), lambda i, j, k, s: (k, j)),          # s_w
-        pl.BlockSpec((1, 1), lambda i, j, k, s: (0, 0)),           # s_x
+        pl.BlockSpec((bb, m), lambda i, j, k, s, o: (i, k)),          # x
+        pl.BlockSpec((1, m, bn), lambda i, j, k, s, o: (k, 0, j)),    # w_q panel
+        pl.BlockSpec((1, 1, bn), lambda i, j, k, s, o: (k, 0, j)),    # s_w
+        pl.BlockSpec((1, 1), lambda i, j, k, s, o: (0, 0)),           # s_x
     ]
-    operands = [x.astype(jnp.float32), w_q, s_w, s_x]
+    operands = [x.astype(jnp.float32), w_q, s_w.reshape(kb, 1, np_), s_x]
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k, s: (0, j)))
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k, s, o: (0, j)))
         operands.append(bias.reshape(1, np_).astype(jnp.float32))
 
     kernel = functools.partial(
@@ -278,15 +300,16 @@ def aimc_matmul_pallas_v2(
         adc_step=float(adc_step), sigma=float(sigma), activation=activation,
         has_bias=has_bias, grid_bij=grid,
         b_total=int(b_logical if b_logical is not None else b),
-        n_total=np_, noise_source=noise_source)
+        n_total=int(n_logical if n_logical is not None else np_),
+        noise_source=noise_source)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
-        out_specs=pl.BlockSpec((bb, bn), lambda i, j, k, s: (i, j)))
+        num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+        out_specs=pl.BlockSpec((bb, bn), lambda i, j, k, s, o: (i, j)))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, np_), jnp.float32),
         interpret=interpret,
-    )(seed, *operands)
+    )(seed, offsets, *operands)
 
 
 # ---------------------------------------------------------------------------
@@ -294,7 +317,8 @@ def aimc_matmul_pallas_v2(
 # ---------------------------------------------------------------------------
 
 
-def _aimc_mvm_kernel_stacked(seed_ref, x_ref, w_ref, sw_ref, sx_ref, *rest,
+def _aimc_mvm_kernel_stacked(seed_ref, off_ref, x_ref, w_ref, sw_ref, sx_ref,
+                             *rest,
                              adc_step: float, sigma: float,
                              activations: tuple[str, ...], has_bias: bool,
                              grid_gbij: tuple[int, int, int, int],
@@ -311,8 +335,9 @@ def _aimc_mvm_kernel_stacked(seed_ref, x_ref, w_ref, sw_ref, sx_ref, *rest,
         seed_g = cprng.stack_seed(seed_ref[0], g)
         grid_dims = ((g, grid_gbij[0]), (i, grid_gbij[1]),
                      (j, grid_gbij[2]), (k, kb))
-        noise = sigma * _in_kernel_noise(seed_g, k, i, j, grid_dims, bb, bn,
-                                         b_total, n_total, noise_source)
+        noise = sigma * _in_kernel_noise(seed_g, off_ref, k, i, j, grid_dims,
+                                         bb, bn, b_total, n_total,
+                                         noise_source)
     s_x = sx_ref[0, 0]
     contrib = _mac_adc_contrib(x_ref[...], w_ref[0, 0], sw_ref[0, 0], s_x,
                                noise, adc_step)
@@ -344,7 +369,7 @@ def _aimc_mvm_kernel_stacked(seed_ref, x_ref, w_ref, sw_ref, sx_ref, *rest,
 @functools.partial(
     jax.jit,
     static_argnames=("adc_step", "sigma", "activations", "block_b", "block_n",
-                     "noise_source", "interpret", "b_logical"),
+                     "noise_source", "interpret", "b_logical", "n_logical"),
 )
 def aimc_matmul_pallas_stacked(
     x, w_q, s_w, s_x, seed=None, bias=None, *,
@@ -354,14 +379,17 @@ def aimc_matmul_pallas_stacked(
     block_b: int = 128,
     block_n: int = 512,
     noise_source: str = "counter",
-    interpret: bool = True,
+    interpret: bool = False,
     b_logical: int | None = None,
+    offsets=None,
+    n_logical: int | None = None,
 ):
     """Gate-fused multi-MVM: `[G, KB, M, Np]` weights, one shared `[B, K]`
     input and DAC scale, `[G, B, Np]` out — ONE weight-stationary
     `pallas_call` for the whole gate/head stack. `activations` is one
     epilogue for all gates or a per-gate tuple of length G; slice g draws
-    noise under `cprng.stack_seed(seed, g)`."""
+    noise under `cprng.stack_seed(seed, g)`. `b_logical`, `n_logical` and
+    `offsets` place a shard as in `aimc_matmul_pallas_v2`."""
     g_, kb, m, np_ = w_q.shape
     if isinstance(activations, str):
         activations = (activations,) * g_
@@ -383,31 +411,35 @@ def aimc_matmul_pallas_stacked(
         seed = jnp.zeros((1,), jnp.uint32)
     else:
         seed = jnp.asarray(seed).reshape((1,)).astype(jnp.uint32)
+    offsets = (jnp.zeros((3,), jnp.int32) if offsets is None
+               else jnp.asarray(offsets, jnp.int32).reshape((3,)))
 
     grid = (g_, b // bb, np_ // bn, kb)
     has_bias = bias is not None
     in_specs = [
-        pl.BlockSpec((bb, m), lambda g, i, j, k, s: (i, k)),           # x (shared)
-        pl.BlockSpec((1, 1, m, bn), lambda g, i, j, k, s: (g, k, 0, j)),
-        pl.BlockSpec((1, 1, bn), lambda g, i, j, k, s: (g, k, j)),     # s_w
-        pl.BlockSpec((1, 1), lambda g, i, j, k, s: (0, 0)),            # s_x
+        pl.BlockSpec((bb, m), lambda g, i, j, k, s, o: (i, k)),           # x (shared)
+        pl.BlockSpec((1, 1, m, bn), lambda g, i, j, k, s, o: (g, k, 0, j)),
+        pl.BlockSpec((1, 1, 1, bn), lambda g, i, j, k, s, o: (g, k, 0, j)),
+        pl.BlockSpec((1, 1), lambda g, i, j, k, s, o: (0, 0)),            # s_x
     ]
-    operands = [x.astype(jnp.float32), w_q, s_w, s_x]
+    operands = [x.astype(jnp.float32), w_q, s_w.reshape(g_, kb, 1, np_), s_x]
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, bn), lambda g, i, j, k, s: (g, j)))
-        operands.append(bias.reshape(g_, np_).astype(jnp.float32))
+        in_specs.append(
+            pl.BlockSpec((1, 1, bn), lambda g, i, j, k, s, o: (g, 0, j)))
+        operands.append(bias.reshape(g_, 1, np_).astype(jnp.float32))
 
     kernel = functools.partial(
         _aimc_mvm_kernel_stacked,
         adc_step=float(adc_step), sigma=float(sigma), activations=activations,
         has_bias=has_bias, grid_gbij=grid,
         b_total=int(b_logical if b_logical is not None else b),
-        n_total=np_, noise_source=noise_source)
+        n_total=int(n_logical if n_logical is not None else np_),
+        noise_source=noise_source)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bb, bn), lambda g, i, j, k, s: (g, i, j)))
+        num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, bb, bn), lambda g, i, j, k, s, o: (g, i, j)))
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g_, b, np_), jnp.float32),
         interpret=interpret,
-    )(seed, *operands)
+    )(seed, offsets, *operands)

@@ -54,6 +54,12 @@ def mix32(x: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
+def to_f32(v: jnp.ndarray) -> jnp.ndarray:
+    """uint32 -> f32 for values below 2**24, through int32: Mosaic has no
+    direct uint32 -> f32 cast, and both steps are exact in that range."""
+    return v.astype(jnp.int32).astype(jnp.float32)
+
+
 def stack_seed(seed: jnp.ndarray, g) -> jnp.ndarray:
     """Per-gate seed of slice `g` in a stacked multi-MVM.
 
@@ -71,8 +77,8 @@ def gauss_from_counter(seed: jnp.ndarray, ctr: jnp.ndarray) -> jnp.ndarray:
     """
     h1 = mix32(_u32(ctr) ^ _u32(seed))
     h2 = mix32(h1 + jnp.uint32(GOLDEN))
-    u1 = ((h1 >> 8).astype(jnp.float32) + 1.0) * jnp.float32(_U24)
-    u2 = (h2 >> 8).astype(jnp.float32) * jnp.float32(_U24)
+    u1 = (to_f32(h1 >> 8) + 1.0) * jnp.float32(_U24)
+    u2 = to_f32(h2 >> 8) * jnp.float32(_U24)
     r = jnp.sqrt(-2.0 * jnp.log(u1))
     return r * jnp.cos(jnp.float32(_TWO_PI) * u2)
 

@@ -39,7 +39,6 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
              exec_mode: str = "digital", variant: str = "baseline",
              out_dir: str = "experiments/dryrun", save: bool = True) -> dict:
     import jax
-    from repro.compat import use_mesh
     from repro.configs import SHAPES, get_arch
     from repro.core.aimc import AimcConfig
     from repro.launch.mesh import make_production_mesh
@@ -52,7 +51,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_dev = mesh.size
     if exec_mode == "aimc":
-        exe = Execution(mode="aimc", aimc=AimcConfig(impl="ref"))
+        exe = Execution(mode="aimc", aimc=AimcConfig())
     elif exec_mode == "int8":
         exe = Execution(serve_int8=True)
     else:
@@ -63,7 +62,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
            "devices": n_dev, "exec": exec_mode, "variant": variant}
     t0 = time.time()
     try:
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             bundle = make_step(spec, cell, mesh, exe)
             jitted = jax.jit(
                 bundle.fn,
@@ -76,8 +75,7 @@ def run_cell(arch_id: str, shape_name: str, multi_pod: bool,
             t2 = time.time()
 
             mem = compiled.memory_analysis()
-            from repro.compat import cost_analysis
-            cost = cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             # while-aware per-device stats: XLA's cost_analysis counts scan
             # bodies ONCE; hlostats multiplies by known_trip_count.
             stats = analyze_hlo(compiled.as_text())

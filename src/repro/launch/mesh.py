@@ -10,6 +10,11 @@ Axis semantics (DESIGN.md §5):
   data   — in-pod data parallel / FSDP axis
   model  — tensor parallel axis (also: MoE experts, decode KV sequence chunks)
 
+Every axis is ``AxisType.Auto``: shardings are GSPMD hints
+(``with_sharding_constraint``, pinned jit in/out shardings) that the
+partitioner propagates. ``jax.make_mesh`` defaults to ``Explicit`` axes,
+under which those hints become asserts.
+
 `fsdp_axes` returns the tuple of axes the parameter/optimizer shards span in
 addition to `model` — on a multi-pod mesh parameters shard over pod+data too,
 so 512 chips hold one copy of (param, grad, moments).
@@ -18,17 +23,18 @@ so 512 chips hold one copy of (param, grad, moments).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Arbitrary mesh for elastic re-shapes / tests (e.g. (1, 1) on CPU)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def fsdp_axes(mesh) -> tuple[str, ...]:

@@ -81,6 +81,7 @@ exactly. ``--heartbeat PATH`` beats a liveness file per chunk/pass.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import time
 
@@ -495,7 +496,8 @@ def parse_tenants(arg: str, specs):
 
 def _run_server(args):
     """The --models path: multi-tenant multi-model serving over one pool."""
-    from repro.compat import use_mesh
+    import jax
+
     from repro.configs import get_arch
     from repro.launch.mesh import make_mesh
     from repro.runtime.server import build_server
@@ -516,7 +518,7 @@ def _run_server(args):
 
     p, g = args.prompt_len, args.gen
     n_slots = args.slots or 4
-    with use_mesh(mesh) if mesh is not None else _nullcontext():
+    with jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
         t0 = time.time()
         server = build_server(
             specs, tenants, smoke=args.smoke, n_slots=n_slots,
@@ -570,19 +572,16 @@ def _run_server(args):
         return report
 
 
-def _nullcontext():
-    import contextlib
-    return contextlib.nullcontext()
-
-
 def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+
     args = parse_args(argv)
+    enable_compile_cache()
     if args.models:
         return _run_server(args)
     import jax
     import jax.numpy as jnp
 
-    from repro.compat import use_mesh
     from repro.configs import get_arch
     from repro.core.aimc import AimcConfig
     from repro.launch.mesh import make_mesh
@@ -608,7 +607,7 @@ def main(argv=None):
         aimc_kw["tile_rows"] = args.tile_rows
     if args.adc_alpha:
         aimc_kw["adc_alpha"] = args.adc_alpha
-    aimc_cfg = AimcConfig(impl="ref", **aimc_kw)
+    aimc_cfg = AimcConfig(**aimc_kw)
     exe = (Execution(mode="aimc", aimc=aimc_cfg, compute_dtype="float32",
                      programmed=not args.reprogram)
            if args.exec_mode == "aimc"
@@ -623,7 +622,7 @@ def main(argv=None):
         min_prompt=cfg.n_patches if spec.family == "vlm" else 1)
     requests = apply_shared_prefix(requests, args.shared_prefix)
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = model.init(jax.random.PRNGKey(args.seed), cfg)
         if args.int8:
             from repro.core.quant import quantize_params_int8

@@ -27,7 +27,7 @@ from repro.launch.shardings import (batch_specs, cache_specs, fit_spec,
                                     fit_specs, get_opt_specs,
                                     get_param_specs, shard_aimc_states,
                                     strip_fsdp)
-from repro.models.layers import Execution
+from repro.models.layers import Execution, at_stated_precision
 from repro.optim import make_optimizer
 
 CE_CHUNK = 512
@@ -212,8 +212,8 @@ def make_train_step(spec: ArchSpec, cell: ShapeCell, mesh,
                 jax.ShapeDtypeStruct((2,), jnp.uint32))
     in_sh = (pspecs, ospecs, bspecs, P())
     out_sh = (pspecs, ospecs, None)
-    return StepBundle(train_step, in_sh, out_sh, abstract,
-                      donate_argnums=(0, 1))
+    return StepBundle(at_stated_precision(train_step, exe), in_sh, out_sh,
+                      abstract, donate_argnums=(0, 1))
 
 
 # ---------------------------------------------------------------------------

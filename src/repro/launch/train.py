@@ -60,10 +60,10 @@ def main(argv=None):
     import jax.numpy as jnp
 
     from repro.checkpoint import checkpoint
-    from repro.compat import use_mesh
     from repro.configs import ShapeCell, get_arch
     from repro.core.aimc import AimcConfig
     from repro.data.pipeline import DataConfig, host_batch, make_global_array
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import dp_axes, make_mesh
     from repro.launch.shardings import to_named
     from repro.launch.steps import make_step
@@ -73,6 +73,7 @@ def main(argv=None):
     from repro.runtime.fault_tolerance import (Heartbeat, StragglerMonitor,
                                                resilient_step)
 
+    enable_compile_cache()
     spec = get_arch(args.arch)
     if args.smoke:
         spec = dataclasses.replace(spec, model_cfg=spec.smoke_cfg)
@@ -85,13 +86,13 @@ def main(argv=None):
     gb = args.global_batch or (4 if args.smoke else 256)
     sl = args.seq_len or (32 if args.smoke else 4096)
     cell = ShapeCell("train_cli", seq_len=sl, global_batch=gb, kind="train")
-    exe = (Execution(mode="aimc", aimc=AimcConfig(impl="ref"),
+    exe = (Execution(mode="aimc", aimc=AimcConfig(),
                      compute_dtype="float32" if args.smoke else "bfloat16")
            if args.exec_mode == "aimc"
            else Execution(compute_dtype="float32" if args.smoke
                           else "bfloat16"))
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = make_step(spec, cell, mesh, exe)
         step_fn = jax.jit(bundle.fn,
                           in_shardings=to_named(bundle.in_shardings, mesh),

@@ -22,7 +22,9 @@ activations (see DESIGN.md §4 applicability boundary).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 from functools import partial
 
 import jax
@@ -59,8 +61,28 @@ class Execution:
     def cdtype(self):
         return jnp.dtype(self.compute_dtype)
 
+    def matmul_precision(self):
+        """Context for tracing model code at the stated compute dtype.
+
+        A TPU runs a float32 matmul at DEFAULT precision as one bf16 MXU
+        pass, or, with one row, as an f32 multiply-reduce, so float32
+        execution pins ``highest``. Narrower dtypes keep the platform
+        default. The CPU computes float32 matmuls in float32 either way."""
+        if self.cdtype == jnp.float32:
+            return jax.default_matmul_precision("highest")
+        return contextlib.nullcontext()
+
 
 DIGITAL = Execution()
+
+
+def at_stated_precision(fn, exe: Execution):
+    """`fn` traced under ``exe.matmul_precision()``: wrap what gets jitted."""
+    @functools.wraps(fn)
+    def traced(*args, **kw):
+        with exe.matmul_precision():
+            return fn(*args, **kw)
+    return traced
 
 
 # ---------------------------------------------------------------------------

@@ -143,8 +143,7 @@ def _moe_sharded(xd, gate_idx, gate_vals, w_gate, w_up, w_down,
         xe_loc, slot_loc = _dispatch_sort(x_loc, ids_loc, e, cap_loc, top_k)
         return xe_loc, slot_loc
 
-    from repro.compat import shard_map
-    xe, slot_o = shard_map(
+    xe, slot_o = jax.shard_map(
         disp_local, mesh=mesh,
         in_specs=(P(dp, None), P(dp, None)),
         out_specs=(P(None, dp, None), P(dp, None)),
@@ -168,7 +167,7 @@ def _moe_sharded(xd, gate_idx, gate_vals, w_gate, w_up, w_down,
 
     # check_vma=False: the model-axis all_gather makes the output
     # replicated over `model`, which the varying-axis checker cannot infer
-    y = shard_map(
+    y = jax.shard_map(
         combine_local, mesh=mesh,
         in_specs=(P("model", dp, None), P(dp, None), P(dp, None)),
         out_specs=P(dp, None), check_vma=False)(ye, slot_o, gate_vals)

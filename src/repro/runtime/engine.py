@@ -93,7 +93,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import Execution, mask_batch_select
+from repro.models.layers import (Execution, at_stated_precision,
+                                 mask_batch_select)
 from repro.runtime.batcher import (Batcher, Request, RequestRecord,
                                    SlotAllocator, percentile)
 from repro.runtime.fault_tolerance import StragglerMonitor, resilient_step
@@ -140,7 +141,7 @@ def static_prefill_closure(model, cfg, exe: Execution, *, family: str = "lm",
         def prefill(params, batch):
             logits, _ = model.forward(params, batch["tokens"], cfg, exe)
             return jnp.argmax(logits[:, -1:], -1).astype(jnp.int32), ()
-    return prefill
+    return at_stated_precision(prefill, exe)
 
 
 def static_decode_closure(model, cfg, exe: Execution) -> Callable:
@@ -149,7 +150,7 @@ def static_decode_closure(model, cfg, exe: Execution) -> Callable:
     def serve_step(params, cache, tokens):
         logits, new_cache = model.decode_step(params, cache, tokens, cfg, exe)
         return jnp.argmax(logits, -1).astype(jnp.int32), new_cache
-    return serve_step
+    return at_stated_precision(serve_step, exe)
 
 
 # ---------------------------------------------------------------------------
@@ -533,7 +534,7 @@ class ServeEngine:
         """Compile the device closures. `ShardedServeEngine` overrides
         this to pin every input/output to a mesh placement; the math
         (`_prefill_fn`/`_insert_fn`/`_decode_fn`) is shared verbatim."""
-        self._jit_prefill = jax.jit(self._prefill_fn)
+        self._jit_prefill = jax.jit(self._traced(self._prefill_fn))
         self._jit_insert = jax.jit(self._insert_fn,
                                    donate_argnums=(0, 2, 4))
         decode_fn = self._decode_fn
@@ -542,11 +543,12 @@ class ServeEngine:
             self._jit_insert_paged = jax.jit(self._insert_paged_fn,
                                              donate_argnums=(0, 2, 4))
         if self._use_legs:
-            self._jit_leg = jax.jit(self._leg_fn, donate_argnums=(2, 3))
+            self._jit_leg = jax.jit(self._traced(self._leg_fn),
+                                    donate_argnums=(2, 3))
             self._jit_register = jax.jit(self._register_fn,
                                          donate_argnums=(0, 1, 2, 4))
         if self._legs_rec:
-            self._jit_leg_rec = jax.jit(self._leg_rec_fn,
+            self._jit_leg_rec = jax.jit(self._traced(self._leg_rec_fn),
                                         donate_argnums=(1,))
         if self._snap:
             self._jit_snap_put = jax.jit(self._snap_put_fn,
@@ -557,7 +559,7 @@ class ServeEngine:
         # to re-present the same input buffers (donation would have
         # invalidated them on the failed attempt)
         self._decode_jits = {
-            n: jax.jit(functools.partial(decode_fn, length=n))
+            n: jax.jit(self._traced(functools.partial(decode_fn, length=n)))
             for n in self._ladder}
         self._safe_decodes = {
             n: resilient_step(f, max_retries=max_retries,
@@ -577,6 +579,11 @@ class ServeEngine:
             raise ValueError(f"no batch axis found in cache leaf {s2}")
 
         return jax.tree.map(axis_of, shapes(2), shapes(3))
+
+    def _traced(self, fn):
+        """A closure that runs model code, traced at the matmul precision
+        the execution states (`Execution.matmul_precision`)."""
+        return at_stated_precision(fn, self.exe)
 
     def _prefill_fn(self, params, tokens, valid_len):
         """[1, prompt_pad] ragged prefill -> (first_tok [1,1], cache1)."""
@@ -810,15 +817,16 @@ class ServeEngine:
     @staticmethod
     def _commit_ambient(tree):
         """Commit creation-fresh buffers to the replicated placement of the
-        ambient mesh, if one is active. Under `use_mesh`, jit OUTPUTS come
+        ambient mesh, if one is active. Under `jax.set_mesh`, jit OUTPUTS come
         back NamedSharding-committed while `jnp.zeros` stays uncommitted —
         and the executable cache keys on placement, so a closure fed a
         fresh buffer at session start and a jit output afterwards would
         compile twice. No ambient mesh: identity (placement is uniform)."""
         import jax.sharding as shd
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.empty:
+
+        from repro.compat import current_mesh
+        mesh = current_mesh()
+        if mesh is None:
             return tree
         sh = shd.NamedSharding(mesh, shd.PartitionSpec())
         return jax.tree_util.tree_map(lambda x: jax.device_put(x, sh), tree)
@@ -1789,6 +1797,18 @@ class ServeEngine:
         return aggregate_core_ledgers(self.schedule, report.records)
 
 
+def _on_mesh(method):
+    """An engine entry point run under ``jax.set_mesh(self.mesh)``: a
+    compiled kernel maps itself over the ambient mesh (GSPMD cannot
+    partition a Mosaic call; kernels/ops.py), and the closures' executable
+    caches key on it."""
+    @functools.wraps(method)
+    def run(self, *args, **kw):
+        with jax.set_mesh(self.mesh):
+            return method(self, *args, **kw)
+    return run
+
+
 class ShardedServeEngine(ServeEngine):
     """`ServeEngine` with its device state laid out over a real JAX mesh.
 
@@ -1829,6 +1849,12 @@ class ShardedServeEngine(ServeEngine):
         self.mesh = mesh
         self.model_axis = model_axis
         super().__init__(model, cfg, exe, params, **kw)
+
+    # every public entry point that reaches a compiled closure
+    warmup = _on_mesh(ServeEngine.warmup)
+    admit = _on_mesh(ServeEngine.admit)
+    step = _on_mesh(ServeEngine.step)
+    serve = _on_mesh(ServeEngine.serve)
 
     def _build_closures(self, max_retries: int):
         from jax.sharding import NamedSharding
@@ -1893,7 +1919,7 @@ class ShardedServeEngine(ServeEngine):
         cache1_sh = named_replicated(cache1_shape)   # [1, ...]: nothing to split
 
         self._jit_prefill = jax.jit(
-            self._prefill_fn,
+            self._traced(self._prefill_fn),
             in_shardings=(self._param_sh, repl, repl),
             out_shardings=(repl, cache1_sh))
         if self._paged_kv:
@@ -1921,7 +1947,7 @@ class ShardedServeEngine(ServeEngine):
         if self._use_legs:
             kp_sh, vp_sh = self._cache_sh["kp"], self._cache_sh["vp"]
             self._jit_leg = jax.jit(
-                self._leg_fn, donate_argnums=(2, 3),
+                self._traced(self._leg_fn), donate_argnums=(2, 3),
                 in_shardings=(self._param_sh, repl, kp_sh, vp_sh, repl,
                               repl, repl),
                 out_shardings=(repl, kp_sh, vp_sh))
@@ -1936,7 +1962,7 @@ class ShardedServeEngine(ServeEngine):
             # pin the carried [1, ...] state replicated: snap_get outputs
             # and fresh init_cache trees must key ONE executable each
             self._jit_leg_rec = jax.jit(
-                self._leg_rec_fn, donate_argnums=(1,),
+                self._traced(self._leg_rec_fn), donate_argnums=(1,),
                 in_shardings=(self._param_sh, cache1_sh, repl, repl),
                 out_shardings=(repl, cache1_sh))
         if self._snap:
@@ -1953,7 +1979,7 @@ class ShardedServeEngine(ServeEngine):
                 out_shardings=cache1_sh)
         self._decode_jits = {
             n: jax.jit(
-                functools.partial(decode_fn, length=n),
+                self._traced(functools.partial(decode_fn, length=n)),
                 in_shardings=(self._param_sh, self._cache_sh, tok_sh,
                               self._state_sh),
                 out_shardings=(tok_sh, self._cache_sh, self._state_sh,
@@ -2017,10 +2043,10 @@ def _static_closures(model, cfg, exe, max_seq, cache_dtype):
     """Jitted static-path closures, cached per configuration — a fresh
     `jax.jit(lambda ...)` per call would recompile every invocation and
     bill the A/B baseline for jit time the engine's warmup doesn't pay."""
-    prefill = jax.jit(lambda pr, tk: model.prefill(
-        pr, tk, cfg, exe, max_seq=max_seq, cache_dtype=cache_dtype))
-    decode = jax.jit(lambda pr, ca, tk: model.decode_step(pr, ca, tk, cfg,
-                                                          exe))
+    prefill = jax.jit(at_stated_precision(lambda pr, tk: model.prefill(
+        pr, tk, cfg, exe, max_seq=max_seq, cache_dtype=cache_dtype), exe))
+    decode = jax.jit(at_stated_precision(
+        lambda pr, ca, tk: model.decode_step(pr, ca, tk, cfg, exe), exe))
     return prefill, decode
 
 

@@ -401,7 +401,7 @@ def build_server(specs: Sequence[ModelSpec],
 
     pool = None
     if any(s.exec_mode == "aimc" for s in specs):
-        aimc_cfg = aimc_cfg or AimcConfig(impl="ref", input_scale=0.1)
+        aimc_cfg = aimc_cfg or AimcConfig(input_scale=0.1)
         pool = TilePool(aimc_cfg, n_contexts=n_contexts,
                         tiles_per_context=tiles_per_context)
 

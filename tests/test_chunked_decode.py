@@ -122,10 +122,18 @@ def test_chunked_sync_trace_bit_equal_static(tfm):
 def test_mid_chunk_eos_retirement_frees_slot_for_next_admit(tfm):
     base = make_engine(tfm, n_slots=1, decode_chunk=1)
     base.warmup()
-    req = Request(rid=0, prompt=tuple(range(1, 9)), max_new=8)
-    ref = base.serve([req]).tokens(0)
+    # ref[0] comes from the prefill, ref[i] from decode step i. The EOS must
+    # FIRST appear at decode step 2 — INSIDE a k=4 chunk — so take the first
+    # prompt whose free run has a token there that it has not emitted yet.
+    for start in range(1, 64):
+        req = Request(rid=0, prompt=tuple(range(start, start + 8)), max_new=8)
+        ref = base.serve([req]).tokens(0)
+        if ref[2] not in ref[:2]:
+            break
+    else:
+        pytest.fail("no prompt emits a fresh token at decode step 2")
     assert len(ref) == 8
-    eos = ref[2]          # emitted at decode step 2 — INSIDE a k=4 chunk
+    eos = ref[2]
     eng = make_engine(tfm, n_slots=1, decode_chunk=4, eos_id=eos)
     eng.warmup()
     # two identical-prompt requests through ONE slot: the second can only
@@ -206,8 +214,14 @@ def test_eos_heavy_trace_reconciles_and_excludes_eos_payload(tfm):
     for rid, rec in report.records.items():
         assert eos not in rec.tokens, rid    # EOS never delivered
         if rec.finish_reason == "eos":
-            # the EOS ride is booked as a vector even though no token lands
-            assert rec.decode_vectors == max(len(rec.tokens), 1), rid
+            # The EOS ride is booked as a vector even though no token
+            # lands: a decode-step EOS after n delivered tokens rode n
+            # steps. A prefill token that IS the EOS retires the request at
+            # admission, as the engine books it: nothing delivered, no
+            # decode step ridden, only the prompt's prefill vectors.
+            assert rec.decode_vectors == len(rec.tokens), rid
+            if not rec.tokens:
+                assert rec.prefill_vectors == len(reqs[rid].prompt), rid
     # both countings agree, and close exactly against the program
     assert report.observed_vectors == report.useful_vectors
     led_sum, static = reconcile(program, report.records,

@@ -164,10 +164,17 @@ def test_eos_retires_early(tfm):
     spec, cfg, model, params = tfm
     base = make_engine(tfm, n_slots=1)
     base.warmup()
-    req = Request(rid=0, prompt=tuple(range(1, 9)), max_new=8)
-    ref = base.serve([req]).tokens(0)
+    # force an early EOS: a token the free run FIRST emits at decode step 2
+    # (ref[0] comes from the prefill), from the first prompt that has one
+    for start in range(1, 64):
+        req = Request(rid=0, prompt=tuple(range(start, start + 8)), max_new=8)
+        ref = base.serve([req]).tokens(0)
+        if ref[2] not in ref[:2]:
+            break
+    else:
+        pytest.fail("no prompt emits a fresh token at decode step 2")
     assert len(ref) == 8
-    eos = ref[2]                                 # force an early EOS
+    eos = ref[2]
     eng = make_engine(tfm, n_slots=1, eos_id=eos)
     eng.warmup()
     report = eng.serve([req])

@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import cost_analysis
 from repro.launch.hlostats import analyze_hlo, parse_module, type_bytes
 
 
@@ -25,7 +24,7 @@ def test_scanfree_matches_xla():
                     jax.ShapeDtypeStruct((128, 512), jnp.float32),
                     jax.ShapeDtypeStruct((512, 1024), jnp.float32),
                     jax.ShapeDtypeStruct((1024, 256), jnp.float32))
-    xla = cost_analysis(comp)
+    xla = comp.cost_analysis()
     mine = analyze_hlo(comp.as_text())
     assert mine["flops"] == pytest.approx(xla["flops"], rel=0.02)
     assert mine["bytes"] == pytest.approx(xla["bytes accessed"], rel=0.10)
@@ -44,7 +43,7 @@ def test_scan_trip_count_multiplied():
     expected = 10 * 2 * 256 ** 3
     assert mine["flops"] == pytest.approx(expected, rel=0.01)
     # XLA undercounts by the trip count — that's the bug we work around
-    assert cost_analysis(comp)["flops"] < expected / 5
+    assert comp.cost_analysis()["flops"] < expected / 5
 
 
 def test_nested_scans_multiply_through():

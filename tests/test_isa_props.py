@@ -91,7 +91,8 @@ floats = st.floats(min_value=-1e4, max_value=1e4, allow_nan=False,
 
 
 @given(st.lists(st.integers(QMIN, QMAX), min_size=1, max_size=64),
-       st.floats(min_value=1e-4, max_value=1e3, allow_nan=False, width=32))
+       st.floats(min_value=float(np.float32(1e-4)), max_value=1e3,
+                 allow_nan=False, width=32))
 @settings(max_examples=100, deadline=None)
 def test_int8_codes_roundtrip_exactly(codes, scale):
     """quantize(dequantize(q)) == q: programmed codes survive a digital

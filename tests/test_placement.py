@@ -44,7 +44,7 @@ from repro.runtime.engine import ServeEngine, static_generate
 # small tiles force the smoke model to overflow a 2-tile budget, and the
 # aimc output at this precision is token-equal to digital on this trace
 ACFG = AimcConfig(impl="ref", adc_alpha=0.5, tile_rows=64)
-SEED = 89
+SEED = 1
 
 
 @pytest.fixture(scope="module")

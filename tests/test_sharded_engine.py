@@ -303,13 +303,13 @@ def test_sharded_engine_bit_equal_across_two_devices():
                                            synchronized_trace)
         from repro.runtime.engine import ServeEngine, ShardedServeEngine
 
-        def check(arch, programmed, shape):
+        def check(arch, programmed, shape, impl="ref"):
             spec = get_arch(arch); cfg = spec.smoke_cfg
             model = spec.model_module()
             params = model.init(jax.random.PRNGKey(0), cfg)
             prog = sched = None
             if programmed:
-                aimc = AimcConfig(impl="ref", input_scale=0.1)
+                aimc = AimcConfig(impl=impl, input_scale=0.1)
                 exe = Execution(mode="aimc", aimc=aimc,
                                 compute_dtype="float32", programmed=True)
                 prog = program_model(params, MappingPlan(n_contexts=2),
@@ -345,7 +345,37 @@ def test_sharded_engine_bit_equal_across_two_devices():
 
         check("granite-8b", True, (2, 1))    # slots over data
         check("granite-8b", True, (1, 2))    # crossbar bit lines over model
+        # the Pallas kernel cannot be partitioned by GSPMD: the dispatch
+        # shard_maps it (kernels/ops.py), bit lines over `model`, rows over
+        # `data`
+        check("granite-8b", True, (1, 2), impl="pallas_interpret")
+        check("granite-8b", True, (2, 1), impl="pallas_interpret")
         check("xlstm-350m", False, (2, 1))   # recurrent state over data
+
+        # read noise under the shard_map: every shard draws its own block
+        # of the one-device counter noise
+        import numpy as np
+        from repro.core.aimc import program_linear, stack_states
+        from repro.core.quant import sym_scale
+        from repro.kernels import ops
+        acfg = AimcConfig(tile_rows=128)
+        kx, k1, k2 = jax.random.split(jax.random.PRNGKey(3), 3)
+        x = jax.random.normal(kx, (8, 256))
+        sts = [program_linear(jax.random.normal(k, (256, 512)) * 0.06, acfg)
+               for k in (k1, k2)]
+        st2 = stack_states(sts)
+        s_x = sym_scale(x).reshape(1, 1)
+        f = jax.jit(lambda x: ops.aimc_matmul_v2(
+            x, sts[0].w_q, sts[0].s_w, s_x, jnp.uint32(5), adc_step=4.0,
+            sigma=2.0, impl="pallas_interpret"))
+        g = jax.jit(lambda x: ops.aimc_matmul_stacked(
+            x, st2.w_q, st2.s_w, s_x, jnp.uint32(5), adc_step=4.0,
+            sigma=2.0, impl="pallas_interpret"))
+        want = (np.asarray(f(x)), np.asarray(g(x)))
+        for shape in ((1, 2), (2, 1)):
+            with jax.set_mesh(make_mesh(shape, ("data", "model"))):
+                got = (np.asarray(f(x)), np.asarray(g(x)))
+            assert all((a == b).all() for a, b in zip(got, want)), shape
         print("SHARDED_BITEQUAL_OK")
     """)
     env = dict(os.environ)

@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import use_mesh
 from repro.configs import ShapeCell, get_arch
 from repro.core.aimc import AimcConfig, program_linear
 from repro.core.coupling import loose_forward, tight_forward
@@ -32,7 +31,7 @@ def _run_train(arch_id, steps=3, exec_mode="digital"):
     mesh = make_mesh((1, 1), ("data", "model"))
     exe = (Execution(mode="aimc", aimc=AimcConfig(tile_rows=128, impl="ref"))
            if exec_mode == "aimc" else Execution())
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = make_step(spec, cell, mesh, exe)
         step = jax.jit(bundle.fn,
                        in_shardings=to_named(bundle.in_shardings, mesh),
@@ -81,7 +80,7 @@ def test_serve_steps_run():
     spec = _tiny_spec("granite_8b")
     cell = ShapeCell("tiny_dec", seq_len=64, global_batch=2, kind="decode")
     mesh = make_mesh((1, 1), ("data", "model"))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = make_step(spec, cell, mesh, Execution())
         step = jax.jit(bundle.fn,
                        in_shardings=to_named(bundle.in_shardings, mesh),
