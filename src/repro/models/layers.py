@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from repro.core.aimc import (AimcConfig, AimcLinearState, aimc_apply,
                              aimc_apply_stacked, aimc_linear_ste)
 from repro.kernels.ref import EPILOGUE_FNS
+from repro.tracing import AIMC_MVM
 
 
 # ---------------------------------------------------------------------------
@@ -198,8 +199,10 @@ def linear(x: jnp.ndarray, w: jnp.ndarray, exe: Execution,
                 "programmed AimcLinearState reached linear() but exe.aimc "
                 "is None — install()ed params require an Execution carrying "
                 "the AimcConfig the program was built with")
-        return aimc_apply(w, x, exe.aimc, key, bias=bias,
-                          activation=activation).astype(exe.cdtype)
+        with jax.named_scope(AIMC_MVM):
+            y = aimc_apply(w, x, exe.aimc, key, bias=bias,
+                           activation=activation)
+        return y.astype(exe.cdtype)
     if exe.mode == "aimc" and not exe.programmed:
         y = aimc_linear_ste(x, as_weight(w, jnp.float32), key, exe.aimc)
         y = y.astype(exe.cdtype)
@@ -224,8 +227,10 @@ def linear_stack(x: jnp.ndarray, ws, exe: Execution,
     `linear()` calls (bit-equal noise-off)."""
     if isinstance(ws, AimcLinearState):
         g = ws.stack_shape[-1]
-        y = aimc_apply_stacked(ws, x, exe.aimc, key, biases=biases,
-                               activations=activations).astype(exe.cdtype)
+        with jax.named_scope(AIMC_MVM):
+            y = aimc_apply_stacked(ws, x, exe.aimc, key, biases=biases,
+                                   activations=activations)
+        y = y.astype(exe.cdtype)
         return tuple(y[i] for i in range(g))
     g = len(ws)
     if isinstance(activations, str):

@@ -25,6 +25,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.aimc import AimcLinearState, stack_states
 from repro.models import moe as moe_lib
 from repro.models.layers import (Execution, as_weight, decode_attention,
@@ -309,31 +310,39 @@ def prefill(params, tokens, cfg: TransformerConfig, exe: Execution = None,
     h = embed_tokens(params, tokens, cfg, exe, patch_embeds)
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
 
+    @jax.named_scope(tracing.BLOCK)
     def body(h, blk):
         keys = [None] * 6
         q, k, v = _qkv(rmsnorm(h, blk["ln1"], cfg.norm_eps), blk, cfg, exe,
                        keys, positions)
-        att = flash_attention(q, k, v, causal=True, q_chunk=cfg.q_chunk,
-                              kv_chunk=cfg.kv_chunk)
+        with jax.named_scope(tracing.ATTENTION):
+            att = flash_attention(q, k, v, causal=True, q_chunk=cfg.q_chunk,
+                                  kv_chunk=cfg.kv_chunk)
         h = h + linear(att.reshape(b, s, -1), blk["wo"], exe, keys[3])
         ff, _ = _ffn(rmsnorm(h, blk["ln2"], cfg.norm_eps), blk, cfg, exe, keys)
-        kc = jnp.zeros((b, max_seq, cfg.n_kv_heads, cfg.hd), cache_dtype)
-        vc = jnp.zeros((b, max_seq, cfg.n_kv_heads, cfg.hd), cache_dtype)
-        kc = jax.lax.dynamic_update_slice(kc, k.astype(cache_dtype), (0, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, v.astype(cache_dtype), (0, 0, 0, 0))
+        with jax.named_scope(tracing.KV_WRITE):
+            kc = jnp.zeros((b, max_seq, cfg.n_kv_heads, cfg.hd), cache_dtype)
+            vc = jnp.zeros((b, max_seq, cfg.n_kv_heads, cfg.hd), cache_dtype)
+            kc = jax.lax.dynamic_update_slice(kc, k.astype(cache_dtype),
+                                              (0, 0, 0, 0))
+            vc = jax.lax.dynamic_update_slice(vc, v.astype(cache_dtype),
+                                              (0, 0, 0, 0))
         return h + ff, (kc, vc)
 
-    h, (ks, vs) = jax.lax.scan(body, h, params["blocks"])
-    h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    unembed = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
-    if valid_len is None:
-        h_last = h[:, -1:]
-        lens = jnp.full((b,), s, jnp.int32)
-    else:
-        lens = valid_len.astype(jnp.int32)
-        idx = jnp.clip(lens - 1, 0, s - 1)
-        h_last = h[jnp.arange(b), idx][:, None]                  # [B, 1, D]
-    logits = h_last.astype(jnp.float32) @ as_weight(unembed, jnp.float32)
+    with jax.named_scope(tracing.LAYERS):
+        h, (ks, vs) = jax.lax.scan(body, h, params["blocks"])
+    with jax.named_scope(tracing.HEAD):
+        h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
+        unembed = (params["embed"].T if cfg.tie_embeddings
+                   else params["unembed"])
+        if valid_len is None:
+            h_last = h[:, -1:]
+            lens = jnp.full((b,), s, jnp.int32)
+        else:
+            lens = valid_len.astype(jnp.int32)
+            idx = jnp.clip(lens - 1, 0, s - 1)
+            h_last = h[jnp.arange(b), idx][:, None]              # [B, 1, D]
+        logits = h_last.astype(jnp.float32) @ as_weight(unembed, jnp.float32)
     cache = {"k": ks, "v": vs, "len": lens}
     return logits, cache
 
@@ -356,29 +365,35 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig,
     pos0 = cache["len"][0]
     row_idx = jnp.clip(cache["len"], 0, max_seq - 1)               # [B]
 
+    @jax.named_scope(tracing.BLOCK)
     def body(h, xs):
         blk, kc, vc = xs
         keys = [None] * 6
         q, k, v = _qkv(rmsnorm(h, blk["ln1"], cfg.norm_eps), blk, cfg, exe,
                        keys, positions)
-        if ragged:
-            kc = _scatter_kv(kc, k, row_idx)
-            vc = _scatter_kv(vc, v, row_idx)
-        else:
-            kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
-                                              (0, pos0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
-                                              (0, pos0, 0, 0))
-        att = decode_attention(q, kc, vc, kv_len=cache["len"] + 1)
+        with jax.named_scope(tracing.KV_WRITE):
+            if ragged:
+                kc = _scatter_kv(kc, k, row_idx)
+                vc = _scatter_kv(vc, v, row_idx)
+            else:
+                kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
+                                                  (0, pos0, 0, 0))
+                vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
+                                                  (0, pos0, 0, 0))
+        with jax.named_scope(tracing.ATTENTION):
+            att = decode_attention(q, kc, vc, kv_len=cache["len"] + 1)
         h = h + linear(att.reshape(b, 1, -1), blk["wo"], exe, keys[3])
         ff, _ = _ffn(rmsnorm(h, blk["ln2"], cfg.norm_eps), blk, cfg, exe, keys)
         return h + ff, (kc, vc)
 
-    h, (ks, vs) = jax.lax.scan(body, h, (params["blocks"],
-                                         cache["k"], cache["v"]))
-    h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    unembed = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
-    logits = h.astype(jnp.float32) @ as_weight(unembed, jnp.float32)
+    with jax.named_scope(tracing.LAYERS):
+        h, (ks, vs) = jax.lax.scan(body, h, (params["blocks"],
+                                             cache["k"], cache["v"]))
+    with jax.named_scope(tracing.HEAD):
+        h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
+        unembed = (params["embed"].T if cfg.tie_embeddings
+                   else params["unembed"])
+        logits = h.astype(jnp.float32) @ as_weight(unembed, jnp.float32)
     new_cache = {"k": ks, "v": vs, "len": cache["len"] + 1}
     return logits, new_cache
 

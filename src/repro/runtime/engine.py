@@ -93,6 +93,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.models.layers import (Execution, at_stated_precision,
                                  mask_batch_select)
 from repro.runtime.batcher import (Batcher, Request, RequestRecord,
@@ -232,12 +233,10 @@ class ServeReport:
         return out
 
     def summary(self) -> str:
-        gen = self.generated_tokens
-        wall = self.wall_prefill_s + self.wall_decode_s
         pct = self.latency_percentiles()
-        return (f"{len(self.records)} requests, {gen} tokens in "
-                f"{self.makespan_s:.2f}s engine-time ({gen / max(wall, 1e-9):.1f}"
-                f" tok/s compute; {self.n_prefills} prefills, {self.n_steps} "
+        return (f"{len(self.records)} requests, {self.generated_tokens} "
+                f"tokens in {self.makespan_s:.2f}s engine-time "
+                f"({self.n_prefills} prefills, {self.n_steps} "
                 f"decode steps, {self.idle_vectors} idle lanes); "
                 f"p50/p99 latency {pct['p50_latency_s']:.2f}/"
                 f"{pct['p99_latency_s']:.2f}s")
@@ -598,7 +597,8 @@ class ServeEngine:
         logits, cache = self.model.prefill(
             params, tokens, self.cfg, self.exe, max_seq=self.max_seq,
             cache_dtype=self.cache_dtype, valid_len=valid_len, **kw)
-        tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+        with jax.named_scope(tracing.HEAD):
+            tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
         return tok, cache
 
     def _insert_fn(self, cache, cache1, tok_buf, tok1, state, slot, pos0,
@@ -651,11 +651,13 @@ class ServeEngine:
             else:
                 logits, new_cache = self.model.decode_step(
                     params, cache, tokens, self.cfg, self.exe)
-            new_cache = jax.tree.map(
-                lambda n, o, ax: mask_batch_select(n, o, active, ax),
-                new_cache, cache, self._axes)
-            tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
-            tok = jnp.where(active[:, None], tok, tokens)
+            with jax.named_scope(tracing.HEAD):
+                tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
+            with jax.named_scope(tracing.LANE_FREEZE):
+                new_cache = jax.tree.map(
+                    lambda n, o, ax: mask_batch_select(n, o, active, ax),
+                    new_cache, cache, self._axes)
+                tok = jnp.where(active[:, None], tok, tokens)
             emitted = tok[:, 0]
             gen = st["gen"] + active.astype(jnp.int32)
             pos = st["pos"] + active.astype(jnp.int32)
@@ -1123,14 +1125,15 @@ class ServeEngine:
         the caller reads the token handle at the next chunk sync — the
         prefill itself queues behind any in-flight chunk on the device, so
         blocking here would stall admission on decode compute."""
-        tokens, vl = self._pad_prompt(req.prompt)
-        t0 = time.perf_counter()
-        tok1, cache1 = self._jit_prefill(self.params, tokens, vl)
-        first = None
-        if not lazy:
-            tok1.block_until_ready()
-            first = int(tok1[0, 0])
-        dt = time.perf_counter() - t0
+        with tracing.span("prefill", rid=req.rid):
+            tokens, vl = self._pad_prompt(req.prompt)
+            t0 = time.perf_counter()
+            tok1, cache1 = self._jit_prefill(self.params, tokens, vl)
+            first = None
+            if not lazy:
+                tok1.block_until_ready()
+                first = int(tok1[0, 0])
+            dt = time.perf_counter() - t0
         rec.prefill_vectors = len(req.prompt)
         rec.pad_vectors = self.prompt_pad - len(req.prompt)
         return tok1, cache1, first, dt
@@ -1182,69 +1185,72 @@ class ServeEngine:
         moves elsewhere) every leg runs before this returns, otherwise the
         job queues on ``sess.jobs`` and `serve()` advances one leg per loop
         iteration, interleaved with decode chunks."""
-        report = sess.report
-        rec = RequestRecord(request=req, t_admit=now)
-        report.records[req.rid] = rec
-        if req.max_new > 1 and (self._use_legs or self._legs_rec):
-            return self._admit_legged(sess, req, rec, now, drain)
-        # ---- dense prefill (also paged mode A: dense prefill, paged
-        # insert) --------------------------------------------------------
-        # with no EOS configured, NOTHING about admission depends on the
-        # first token's value — defer the host read to the next chunk sync
-        # so admission overlaps the in-flight chunk instead of waiting
-        # behind it on the device queue
-        lazy = self.eos_id is None
-        tok1, cache1, first, dt = self._prefill_request(req, rec, lazy)
-        now += dt
-        report.wall_prefill_s += dt
-        report.n_prefills += 1
-        report.prefill_pad_vectors += rec.pad_vectors
-        report.observed_vectors += len(req.prompt)
-        rec.t_first = now
-        if lazy:
-            sess.lazy.append((rec, tok1))
-        else:
-            eos_hit = first == self.eos_id
-            if not eos_hit:
-                # the EOS token is control, not payload: it never lands in
-                # `rec.tokens` (so generated_tokens / tok_s count delivered
-                # output only), but its vector stays in the CM_* books
-                rec.tokens.append(first)
-            if eos_hit:
-                self._retire(rec, "eos", now)
+        with tracing.span("admit", rid=req.rid):
+            report = sess.report
+            rec = RequestRecord(request=req, t_admit=now)
+            report.records[req.rid] = rec
+            if req.max_new > 1 and (self._use_legs or self._legs_rec):
+                return self._admit_legged(sess, req, rec, now, drain)
+            # ---- dense prefill (also paged mode A: dense prefill, paged
+            # insert) ----------------------------------------------------
+            # with no EOS configured, NOTHING about admission depends on
+            # the first token's value — defer the host read to the next
+            # chunk sync so admission overlaps the in-flight chunk instead
+            # of waiting behind it on the device queue
+            lazy = self.eos_id is None
+            tok1, cache1, first, dt = self._prefill_request(req, rec, lazy)
+            now += dt
+            report.wall_prefill_s += dt
+            report.n_prefills += 1
+            report.prefill_pad_vectors += rec.pad_vectors
+            report.observed_vectors += len(req.prompt)
+            rec.t_first = now
+            if lazy:
+                sess.lazy.append((rec, tok1))
+            else:
+                eos_hit = first == self.eos_id
+                if not eos_hit:
+                    # the EOS token is control, not payload: it never lands in
+                    # `rec.tokens` (so generated_tokens / tok_s count delivered
+                    # output only), but its vector stays in the CM_* books
+                    rec.tokens.append(first)
+                if eos_hit:
+                    self._retire(rec, "eos", now)
+                    return now
+            if req.max_new == 1:
+                self._retire(rec, "length", now)
                 return now
-        if req.max_new == 1:
-            self._retire(rec, "length", now)
+            slot = sess.slots.alloc(req.rid)
+            sess.slot_rec[slot] = rec
+            rem = req.max_new - 1
+            if not self.recurrent:
+                rem = min(rem, self.max_seq - len(req.prompt))
+            sess.rem[slot] = rem
+            t0 = time.perf_counter()
+            with tracing.span("insert", rid=req.rid, slot=slot):
+                if self._paged_kv:
+                    pt_row, mask, owned = self._alloc_pt_row(req)
+                    sess.slot_pages[slot] = (owned, [])
+                    (sess.cache, sess.tok_buf,
+                     sess.state) = self._jit_insert_paged(
+                        sess.cache, cache1, sess.tok_buf, tok1, sess.state,
+                        jnp.int32(slot), jnp.int32(len(req.prompt)),
+                        jnp.int32(req.max_new), pt_row, mask)
+                else:
+                    sess.cache, sess.tok_buf, sess.state = self._jit_insert(
+                        sess.cache, cache1, sess.tok_buf, tok1, sess.state,
+                        jnp.int32(slot), jnp.int32(len(req.prompt)),
+                        jnp.int32(req.max_new))
+            if not lazy:
+                # the blocking (EOS-aware) path bills the full prefill+insert
+                # wall here; the lazy path bills dispatch only — the device
+                # time lands in the next chunk's window, where it actually
+                # serializes (insert chains on the in-flight chunk's outputs)
+                sess.tok_buf.block_until_ready()
+            ins = time.perf_counter() - t0
+            now += ins
+            report.wall_prefill_s += ins
             return now
-        slot = sess.slots.alloc(req.rid)
-        sess.slot_rec[slot] = rec
-        rem = req.max_new - 1
-        if not self.recurrent:
-            rem = min(rem, self.max_seq - len(req.prompt))
-        sess.rem[slot] = rem
-        t0 = time.perf_counter()
-        if self._paged_kv:
-            pt_row, mask, owned = self._alloc_pt_row(req)
-            sess.slot_pages[slot] = (owned, [])
-            sess.cache, sess.tok_buf, sess.state = self._jit_insert_paged(
-                sess.cache, cache1, sess.tok_buf, tok1, sess.state,
-                jnp.int32(slot), jnp.int32(len(req.prompt)),
-                jnp.int32(req.max_new), pt_row, mask)
-        else:
-            sess.cache, sess.tok_buf, sess.state = self._jit_insert(
-                sess.cache, cache1, sess.tok_buf, tok1, sess.state,
-                jnp.int32(slot), jnp.int32(len(req.prompt)),
-                jnp.int32(req.max_new))
-        if not lazy:
-            # the blocking (EOS-aware) path bills the full prefill+insert
-            # wall here; the lazy path bills dispatch only — the device
-            # time lands in the next chunk's window, where it actually
-            # serializes (insert chains on the in-flight chunk's outputs)
-            sess.tok_buf.block_until_ready()
-        ins = time.perf_counter() - t0
-        now += ins
-        report.wall_prefill_s += ins
-        return now
 
     # -- paged admission (DESIGN.md §15) -------------------------------------
     def _pages_span(self, req: Request) -> tuple[int, int]:
@@ -1575,8 +1581,9 @@ class ServeEngine:
         if n is None:
             n = self._pick_chunk(sess) or 1
         t0 = time.perf_counter()
-        sess.tok_buf, sess.cache, sess.state, ys = self._safe_decodes[n](
-            self.params, sess.cache, sess.tok_buf, sess.state)
+        with tracing.span("dispatch", n=n):
+            sess.tok_buf, sess.cache, sess.state, ys = self._safe_decodes[n](
+                self.params, sess.cache, sess.tok_buf, sess.state)
         for slot in sess.slot_rec:
             sess.rem[slot] = max(0, sess.rem.get(slot, 0) - n)
         self._chunks_dispatched += 1
@@ -1593,57 +1600,59 @@ class ServeEngine:
         rows into the host books. Billing: the chunk costs (wall since
         dispatch) minus any prefill/insert wall already billed inside that
         window — the double-buffered loop admits WHILE a chunk flies."""
-        report = sess.report
-        toks, acts, reasons = jax.device_get(pend.ys)
+        with tracing.span("sync"):
+            toks, acts, reasons = jax.device_get(pend.ys)
         # any admission since the last sync has its prefill long done by
         # now (the chunk we just read back queued after it) — the deferred
         # first-token reads cost a host copy, not a wait
         self._resolve_firsts(sess)
-        overlap = ((report.wall_prefill_s - pend.prefill0)
-                   + (report.wall_health_s - pend.health0)
-                   + (report.wall_swap_s - pend.swap0))
-        dt = max(time.perf_counter() - pend.t_wall - overlap, 0.0)
-        now += dt
-        report.wall_decode_s += dt
-        ran = int(toks.shape[0])
-        busy = int(acts.sum())
-        report.n_steps += ran
-        # busy-lane counts come from the DEVICE (chunk ys), independent of
-        # the per-request records — reconcile compares two real countings
-        report.observed_vectors += busy
-        report.idle_vectors += self.n_slots * ran - busy
-        self._step_no += ran
-        # a chunk whose window held a hot reprogram is legitimately slow:
-        # exempt it from the straggler EWMA (flagging recovery would page
-        # an operator for behavior the engine itself caused, and the
-        # inflated sample would poison the baseline)
-        self.monitor.record(self._step_no, dt / max(ran, 1),
-                            exempt=(report.n_recals > pend.recals0
-                                    or report.n_swaps > pend.swaps0))
-        if self.heartbeat is not None:
-            self.heartbeat.beat(
-                self._step_no, slots_busy=sess.slots.n_busy,
-                slots_free=sess.slots.n_free, chunk_len=ran,
-                last_chunk_s=time.time(),
-                wall_decode_s=report.wall_decode_s,
-                n_recals=report.n_recals)
+        with tracing.span("books"):
+            report = sess.report
+            overlap = ((report.wall_prefill_s - pend.prefill0)
+                       + (report.wall_health_s - pend.health0)
+                       + (report.wall_swap_s - pend.swap0))
+            dt = max(time.perf_counter() - pend.t_wall - overlap, 0.0)
+            now += dt
+            report.wall_decode_s += dt
+            ran = int(toks.shape[0])
+            busy = int(acts.sum())
+            report.n_steps += ran
+            # busy-lane counts come from the DEVICE (chunk ys), independent of
+            # the per-request records — reconcile compares two real countings
+            report.observed_vectors += busy
+            report.idle_vectors += self.n_slots * ran - busy
+            self._step_no += ran
+            # a chunk whose window held a hot reprogram is legitimately slow:
+            # exempt it from the straggler EWMA (flagging recovery would page
+            # an operator for behavior the engine itself caused, and the
+            # inflated sample would poison the baseline)
+            self.monitor.record(self._step_no, dt / max(ran, 1),
+                                exempt=(report.n_recals > pend.recals0
+                                        or report.n_swaps > pend.swaps0))
+            if self.heartbeat is not None:
+                self.heartbeat.beat(
+                    self._step_no, slots_busy=sess.slots.n_busy,
+                    slots_free=sess.slots.n_free, chunk_len=ran,
+                    last_chunk_s=time.time(),
+                    wall_decode_s=report.wall_decode_s,
+                    n_recals=report.n_recals)
 
-        for s in range(ran):
-            for slot in list(sess.slot_rec):
-                if not acts[s, slot]:
-                    continue    # freed/refilled after this chunk's dispatch
-                rec = sess.slot_rec[slot]
-                rec.decode_vectors += 1
-                r = int(reasons[s, slot])
-                if r != 2:      # EOS is control, not payload (see admit)
-                    rec.tokens.append(int(toks[s, slot]))
-                if r:
-                    self._retire(rec, _REASONS[r], now)
-                    sess.slot_rec.pop(slot)
-                    sess.slots.release(slot)
-                    sess.rem.pop(slot, None)
-                    self._release_slot_pages(sess, slot)
-        return now
+            for s in range(ran):
+                for slot in list(sess.slot_rec):
+                    if not acts[s, slot]:
+                        continue    # freed/refilled after the dispatch
+                    rec = sess.slot_rec[slot]
+                    rec.decode_vectors += 1
+                    r = int(reasons[s, slot])
+                    if r != 2:      # EOS is control, not payload (see admit)
+                        rec.tokens.append(int(toks[s, slot]))
+                    if r:
+                        self._retire(rec, _REASONS[r], now)
+                        sess.slot_rec.pop(slot)
+                        sess.slots.release(slot)
+                        sess.rem.pop(slot, None)
+                        self._release_slot_pages(sess, slot)
+            return now
 
     @staticmethod
     def _resolve_firsts(sess: "EngineSession"):
@@ -1652,7 +1661,8 @@ class ServeEngine:
         a record admitted after a chunk's dispatch shows acts=False for
         that whole chunk, so its first token always lands at index 0."""
         for rec, tok1 in sess.lazy:
-            rec.tokens.insert(0, int(tok1[0, 0]))
+            with tracing.span("first_token", rid=rec.request.rid):
+                rec.tokens.insert(0, int(tok1[0, 0]))
         sess.lazy.clear()
 
     def step(self, sess: "EngineSession", now: float) -> float:
