@@ -129,9 +129,12 @@ def mask_batch_select(new, old, active, axis: int = 0):
     """Per-request freeze: keep `new` where `active`, else `old`.
 
     `active`: [B] bool; `axis` is the batch axis of the (same-shape) arrays.
-    The continuous-batching engine uses this to make a retired/empty slot's
-    state bit-frozen through a decode step — the dense batch still computes
-    the slot's lane, but none of its cache/recurrent state advances."""
+    The freeze for O(1)-per-slot recurrent state: the continuous-batching
+    engine selects a retired/empty slot's old state through a decode step,
+    and `recurrent_prefill` through a prompt's padding. A transformer's KV
+    cache is not frozen this way — a select reads and writes the whole
+    cache every step; `transformer.decode_step(active=...)` drops the
+    frozen lanes' rows instead."""
     shape = [1] * new.ndim
     shape[axis] = active.shape[0]
     return jnp.where(active.reshape(shape), new, old)

@@ -348,15 +348,23 @@ def prefill(params, tokens, cfg: TransformerConfig, exe: Execution = None,
 
 
 def decode_step(params, cache, tokens, cfg: TransformerConfig,
-                exe: Execution = None, ragged: bool = False):
+                exe: Execution = None, ragged: bool = False, active=None):
     """tokens: [B, 1] one new token per sequence -> (logits [B,1,V], cache).
 
     ``ragged=False`` is the lockstep fast path (decode_32k/long_500k cells:
     every sequence is at the same position, so one dynamic_update_slice
     writes the whole batch). ``ragged=True`` is the continuous-batching
     contract: each row writes its K/V at its OWN ``cache["len"]`` position
-    (row scatter, `_scatter_kv`) and attends over its own valid length —
-    slots prefilled at different times decode side by side in one batch."""
+    (a row scatter) and attends over its own valid length — slots
+    prefilled at different times decode side by side in one batch.
+
+    The layer scan writes the new K/V row into its local layer slice, so
+    attention sees the current token, but emits only the rows; they reach
+    the stacked cache after the scan, in place (`_scatter_kv`), and the
+    step never rewrites the whole cache. ``active`` ([B] bool) freezes the
+    lanes it marks False: their rows are dropped and their ``len`` holds —
+    bit for bit what a select of the old cache over those lanes gave. With
+    ``active=None`` every lane writes and advances."""
     exe = exe or Execution()
     b = tokens.shape[0]
     h = jnp.take(params["embed"], tokens, axis=0).astype(exe.cdtype)
@@ -364,6 +372,7 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig,
     max_seq = cache["k"].shape[2]
     pos0 = cache["len"][0]
     row_idx = jnp.clip(cache["len"], 0, max_seq - 1)               # [B]
+    lanes = jnp.arange(b)
 
     @jax.named_scope(tracing.BLOCK)
     def body(h, xs):
@@ -372,9 +381,9 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig,
         q, k, v = _qkv(rmsnorm(h, blk["ln1"], cfg.norm_eps), blk, cfg, exe,
                        keys, positions)
         with jax.named_scope(tracing.KV_WRITE):
-            if ragged:
-                kc = _scatter_kv(kc, k, row_idx)
-                vc = _scatter_kv(vc, v, row_idx)
+            if ragged:        # a row scatter, not a one-hot multiply
+                kc = kc.at[lanes, row_idx].set(k[:, 0].astype(kc.dtype))
+                vc = vc.at[lanes, row_idx].set(v[:, 0].astype(vc.dtype))
             else:
                 kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
                                                   (0, pos0, 0, 0))
@@ -384,27 +393,42 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig,
             att = decode_attention(q, kc, vc, kv_len=cache["len"] + 1)
         h = h + linear(att.reshape(b, 1, -1), blk["wo"], exe, keys[3])
         ff, _ = _ffn(rmsnorm(h, blk["ln2"], cfg.norm_eps), blk, cfg, exe, keys)
-        return h + ff, (kc, vc)
+        return h + ff, (k[:, 0], v[:, 0])
 
     with jax.named_scope(tracing.LAYERS):
         h, (ks, vs) = jax.lax.scan(body, h, (params["blocks"],
                                              cache["k"], cache["v"]))
+    with jax.named_scope(tracing.KV_WRITE):
+        # the lockstep write clamps at the cap as dynamic_update_slice does
+        idx = row_idx if ragged else jnp.broadcast_to(
+            jnp.clip(pos0, 0, max_seq - 1), (b,))
+        if active is not None:
+            idx = jnp.where(active, idx, max_seq)           # dropped rows
+        new_cache = {"k": _scatter_kv(cache["k"], ks, idx),
+                     "v": _scatter_kv(cache["v"], vs, idx),
+                     "len": cache["len"] + (1 if active is None else
+                                            active.astype(jnp.int32))}
     with jax.named_scope(tracing.HEAD):
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         unembed = (params["embed"].T if cfg.tie_embeddings
                    else params["unembed"])
         logits = h.astype(jnp.float32) @ as_weight(unembed, jnp.float32)
-    new_cache = {"k": ks, "v": vs, "len": cache["len"] + 1}
     return logits, new_cache
 
 
-def _scatter_kv(cache_l, new, idx):
-    """cache_l: [B, S, H, D]; new: [B, 1, H, D]; idx: [B] write positions.
+def _scatter_kv(cache, rows, idx):
+    """cache: [L, B, S, H, D]; rows: [L, B, H, D]; idx: [B] write positions
+    (an index of ``S`` or more drops that lane's rows).
 
-    A row scatter (writes B rows in place) — NOT a one-hot multiply, which
-    reads + rewrites the entire cache every layer."""
-    b = cache_l.shape[0]
-    return cache_l.at[jnp.arange(b), idx].set(new[:, 0].astype(cache_l.dtype))
+    One row scatter per layer, with the layer index static, updates the
+    stacked cache in place: L x B rows written, nothing else read or
+    copied. A single scatter over ``[:, lanes, idx]`` is NOT equivalent in
+    cost — XLA's layout assignment gives it whole-cache copies."""
+    lanes = jnp.arange(cache.shape[1])
+    rows = rows.astype(cache.dtype)
+    for layer in range(cache.shape[0]):
+        cache = cache.at[layer, lanes, idx].set(rows[layer], mode="drop")
+    return cache
 
 
 # ---------------------------------------------------------------------------

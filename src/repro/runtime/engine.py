@@ -18,7 +18,9 @@ and admission):
                       is inserted into the lane at the request's own length,
                       and the dense decode batch advances every lane at
                       once — retired/free lanes compute but are bit-frozen
-                      (`mask_batch_select`), so they never corrupt state or
+                      (transformer: their K/V rows are dropped and their
+                      length held inside `decode_step`; recurrent state:
+                      `mask_batch_select`), so they never corrupt state or
                       accounting.
 
   chunked decode      the decode closure advances ``decode_chunk`` steps
@@ -622,15 +624,18 @@ class ServeEngine:
 
     def _decode_fn(self, params, cache, tok_buf, state, length):
         """``length`` dense decode steps in ONE jitted `lax.scan`; inactive
-        lanes are bit-frozen. Retirement predicates (max_new / EOS /
-        max_seq cap) are traced, so the active mask and per-lane counters
-        never leave the device mid-chunk. ``length`` is host-chosen per
-        dispatch from the COMPILED LADDER (`_chunk_ladder`): the host
-        mirrors every lane's length/cap budget exactly, so it picks the
-        largest ladder length no greater than the longest remaining budget
-        — a chunk never runs past the last live lane (the fixed-k variant
-        over-ran ragged traces by 2-3x decode steps at k=8), and the
-        device needs no early-exit predicate (an `active.any()` loop
+        lanes are bit-frozen — a transformer's `decode_step` takes the
+        lane mask and writes only active lanes' K/V rows (no pass over the
+        whole cache), recurrent state goes through `mask_batch_select`.
+        Retirement predicates (max_new / EOS / max_seq cap) are traced,
+        so the active mask and per-lane counters never leave the device
+        mid-chunk. ``length`` is host-chosen per dispatch from the
+        COMPILED LADDER (`_chunk_ladder`): the host mirrors every lane's
+        length/cap budget exactly, so it picks the largest ladder length
+        no greater than the longest remaining budget — a chunk never runs
+        past the last live lane (the fixed-k variant over-ran ragged
+        traces by 2-3x decode steps at k=8), and the device needs no
+        early-exit predicate (an `active.any()` loop
         condition would be a per-token cross-device collective).
 
         Returns (tok_buf, cache, state, ys) with per-step chunk outputs
@@ -646,17 +651,21 @@ class ServeEngine:
             cache, tokens, st = carry
             active = st["active"]
             if self.module == "transformer":
-                logits, new_cache = self.model.decode_step(
-                    params, cache, tokens, self.cfg, self.exe, ragged=True)
+                # frozen lanes' K/V rows are dropped in place, their len held
+                logits, cache = self.model.decode_step(
+                    params, cache, tokens, self.cfg, self.exe, ragged=True,
+                    active=active)
             else:
                 logits, new_cache = self.model.decode_step(
                     params, cache, tokens, self.cfg, self.exe)
+                # O(1) recurrent state per slot: the select is the freeze
+                with jax.named_scope(tracing.LANE_FREEZE):
+                    cache = jax.tree.map(
+                        lambda n, o, ax: mask_batch_select(n, o, active, ax),
+                        new_cache, cache, self._axes)
             with jax.named_scope(tracing.HEAD):
                 tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
             with jax.named_scope(tracing.LANE_FREEZE):
-                new_cache = jax.tree.map(
-                    lambda n, o, ax: mask_batch_select(n, o, active, ax),
-                    new_cache, cache, self._axes)
                 tok = jnp.where(active[:, None], tok, tokens)
             emitted = tok[:, 0]
             gen = st["gen"] + active.astype(jnp.int32)
@@ -673,7 +682,7 @@ class ServeEngine:
             reason = jnp.where(active, reason, 0)
             new_st = {"active": active & (reason == 0), "gen": gen,
                       "pos": pos, "max_new": st["max_new"]}
-            return (new_cache, tok, new_st), (emitted, active, reason)
+            return (cache, tok, new_st), (emitted, active, reason)
 
         (cache, tok_buf, state), ys = jax.lax.scan(
             one_step, (cache, tok_buf, state), None, length=length)
@@ -769,9 +778,11 @@ class ServeEngine:
         same ops as the dense cache rows (prefill or a previous readback-
         identical scatter), and `decode_attention` masks pre-softmax, so
         decode is BIT-EQUAL to the dense engine. Inactive lanes scatter to
-        the reserved SCRATCH page — the paged twin of `mask_batch_select`'s
-        bit-freeze (their table rows may be stale after retirement; scratch
-        absorbs the write and the gathered view is masked by ``len``)."""
+        the reserved SCRATCH page — the paged twin of the dense engine's
+        dropped rows (their table rows may be stale after retirement;
+        scratch absorbs the write and the gathered view is masked by
+        ``len``). The view is a per-step temporary, so `decode_step` runs
+        without ``active`` here and every lane's row is read back."""
         pt = cache["pt"]
         p = self.page_size
         rows = jnp.arange(self.n_slots)

@@ -7,7 +7,7 @@ every admission. This module owns the digital-side fix (DESIGN.md §15):
   * `PageAllocator` — a pool of ``n_pages`` physical pages (page 0 is a
     reserved SCRATCH page that is never allocated: traced writes for
     inactive/frozen lanes route there, mirroring how the dense engine's
-    `mask_batch_select` discards frozen-lane writes). Every other page is
+    `transformer.decode_step` drops frozen-lane writes). Every other page is
     at any instant EXACTLY one of: on the free list, or held with a
     positive refcount under one producing owner — the same
     every-tile-accounted discipline `core.program.TilePool` applies to

@@ -20,7 +20,9 @@ and the compiled code is the same with or without them.
   attention    the attention of one block (decode or prefill)
   kv_write     writing the new keys and values into the cache
   head         the final norm, the unembedding and the argmax
-  lane_freeze  the engine's select that keeps frozen decode lanes unchanged
+  lane_freeze  the engine's selects that keep frozen decode lanes unchanged:
+               the emitted token, and the state of a recurrent model (a
+               transformer's frozen K/V rows are dropped under kv_write)
 """
 
 from __future__ import annotations

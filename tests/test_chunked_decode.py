@@ -15,9 +15,13 @@ The contracts pinned here (ISSUE 7 acceptance criteria):
     EOS-heavy trace still reconciles exactly;
   * the decode step performs NO host->device transfer (the active mask
     lives on device — the PR-7 fix for the per-step `jnp.asarray(active)`
-    rebuild), enforced with a transfer guard.
+    rebuild), enforced with a transfer guard;
+  * `transformer.decode_step(active=...)`, which drops frozen lanes' K/V
+    rows in place, is bit-equal to a decode step followed by a
+    `mask_batch_select` of the whole cache.
 """
 
+import dataclasses
 import os
 import subprocess
 import sys
@@ -25,12 +29,13 @@ import textwrap
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_arch
 from repro.core.aimc import AimcConfig
 from repro.core.program import MappingPlan, program_model
-from repro.models.layers import Execution
+from repro.models.layers import Execution, mask_batch_select
 from repro.runtime.batcher import (Request, poisson_trace, reconcile,
                                    synchronized_trace)
 from repro.runtime.engine import ServeEngine, static_generate
@@ -251,6 +256,60 @@ def test_decode_step_performs_no_host_to_device_transfer(tfm):
 
 
 # ---------------------------------------------------------------------------
+# lane-masked K/V row writes == decode step + whole-cache select
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("qkv_bias", [False, True],
+                         ids=["granite", "qkv_bias"])
+@pytest.mark.parametrize("ragged", [True, False], ids=["ragged", "lockstep"])
+def test_masked_row_write_bit_equal_to_cache_select(tfm, ragged, qkv_bias):
+    spec, cfg, model, params = tfm
+    if qkv_bias:             # glm-like: q/k/v projections carry a bias
+        cfg = dataclasses.replace(cfg, qkv_bias=True)
+        params = model.init(jax.random.PRNGKey(1), cfg)
+    b, max_seq, n_steps = 4, 12, 5
+    kk, kv, kt = jax.random.split(jax.random.PRNGKey(7), 3)
+    shape = (cfg.n_layers, b, max_seq, cfg.n_kv_heads, cfg.hd)
+    # lane 2 sits at the max_seq cap, lane 3 reaches it in the first step
+    lens = [3, 7, max_seq, max_seq - 1] if ragged else [max_seq - 3] * b
+    cache = {"k": jax.random.normal(kk, shape, jnp.float32),
+             "v": jax.random.normal(kv, shape, jnp.float32),
+             "len": jnp.asarray(lens, jnp.int32)}
+    rng = np.random.RandomState(11)
+    masks = rng.rand(n_steps, b) < 0.6
+    masks[0] = [True, False, True, True]
+    assert masks.any(0).all() and not masks.all(0).any()
+    tokens = jax.random.randint(kt, (n_steps, b, 1), 0, cfg.vocab)
+
+    @jax.jit
+    def select_step(cache, tok, active):
+        logits, new = model.decode_step(params, cache, tok, cfg, EXE,
+                                        ragged=ragged)
+        return logits, {k: mask_batch_select(new[k], cache[k], active,
+                                             axis=0 if k == "len" else 1)
+                        for k in new}
+
+    @jax.jit
+    def masked_step(cache, tok, active):
+        return model.decode_step(params, cache, tok, cfg, EXE,
+                                 ragged=ragged, active=active)
+
+    old = new = cache
+    for t in range(n_steps):
+        active = jnp.asarray(masks[t])
+        logits_o, old = select_step(old, tokens[t], active)
+        prev = new
+        logits_n, new = masked_step(new, tokens[t], active)
+        assert np.array_equal(logits_n, logits_o), t
+        for key in ("k", "v", "len"):
+            assert np.array_equal(new[key], old[key]), (t, key)
+        idle = ~masks[t]
+        for key in ("k", "v"):
+            assert np.array_equal(new[key][:, idle], prev[key][:, idle])
+        assert np.array_equal(new["len"], prev["len"] + masks[t])
+
+
+# ---------------------------------------------------------------------------
 # forced 2-device meshes: chunked decode bit-equal to single-device
 # (subprocess — XLA's device count is fixed at backend init)
 # ---------------------------------------------------------------------------
@@ -268,7 +327,7 @@ def test_chunked_sharded_bit_equal_across_two_devices():
         from repro.core.aimc import AimcConfig
         from repro.core.program import MappingPlan, program_model
         from repro.launch.mesh import make_mesh
-        from repro.models.layers import Execution
+        from repro.models.layers import Execution, mask_batch_select
         from repro.runtime.batcher import reconcile, synchronized_trace
         from repro.runtime.engine import ServeEngine, ShardedServeEngine
 

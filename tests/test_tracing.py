@@ -5,6 +5,9 @@
   crossbar states) name every scope in their debug text, and the layer
   scan's slices of its stacked inputs sit under ``layers`` but under no
   ``block``.
+* The compiled decode closure's loop writes the stacked K/V cache only
+  through row scatters: no select, copy or dynamic-update-slice there
+  produces a whole stacked cache.
 * Engine spans: a profiler trace of a short `serve()`, and of the session
   primitives (`admit`/`step`), holds ``engine.admit``, ``engine.prefill``,
   ``engine.insert`` and ``engine.first_token`` once per request with its
@@ -13,6 +16,7 @@
 """
 
 import glob
+import math
 import os
 import re
 
@@ -26,6 +30,10 @@ from repro.runtime.engine import ServeReport
 SMOKE_ENGINE = {"n_slots": 4, "prompt_pad": 16, "max_seq": 40,
                 "decode_chunk": 4}
 _LOC = re.compile(r'^#(loc\d+) = loc\("([^"]*)"', re.M)
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_INSTR = re.compile(r"^(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
+                    r"([\w\-]+)\(")
+_CALLEE = re.compile(r"\b(?:body|condition|calls|to_apply)=%([\w.\-]+)")
 _SLICE = re.compile(r"stablehlo\.dynamic_slice %[\w#]+(?:, %[\w#]+)*, "
                     r"sizes = \[1(?:, \d+)*\] : \(tensor<(\d+)x[^)]*\).*"
                     r"loc\(#(loc\d+)\)")
@@ -87,6 +95,55 @@ def test_layer_scan_slices_sit_under_layers_not_block(sut, closure):
     for name in locs:
         parts = name.split("/")
         assert tracing.LAYERS in parts and tracing.BLOCK not in parts, name
+
+
+def loop_ops(hlo: str) -> list:
+    """(dtype, element count, opcode) of every array-valued instruction in
+    the compiled module's while loops, the computations they call included
+    (fusions, nested loops); the entry computation's own ops are left out."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and line.startswith("  "):
+            cur.append(line.strip())
+    todo = [m.group(1) for body in comps.values() for ins in body
+            for m in re.finditer(r"\bbody=%([\w.\-]+)", ins)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        todo += [c for ins in comps[name] for c in _CALLEE.findall(ins)]
+    ops = []
+    for name in seen:
+        for ins in comps[name]:
+            m = _INSTR.match(ins)
+            if m:
+                dims = [int(d) for d in m.group(2).split(",") if d]
+                ops.append((m.group(1), math.prod(dims), m.group(3)))
+    return ops
+
+
+def test_decode_loop_writes_only_cache_rows(sut):
+    """The decode step writes one K and one V row per lane and layer: the
+    loop holds no select (lane freeze), copy or dynamic-update-slice
+    (layer-scan write-back) the size of the stacked cache, only scatters."""
+    eng = sut.engine
+    sess = eng.begin()
+    fn = eng._decode_jits[eng._ladder[-1]]
+    hlo = fn.lower(eng.params, sess.cache, sess.tok_buf,
+                   sess.state).compile().as_text()
+    stacked = math.prod(sess.cache["k"].shape)
+    assert sess.cache["k"].shape == (sut.cfg.n_layers, eng.n_slots,
+                                     eng.max_seq, sut.cfg.n_kv_heads,
+                                     sut.cfg.hd)
+    whole = [op for dtype, n, op in loop_ops(hlo)
+             if dtype == "f32" and n == stacked]
+    assert "scatter" in whole
+    assert not {"select", "copy", "dynamic-update-slice"} & set(whole), whole
 
 
 def engine_spans(trace_dir: str) -> list:
