@@ -1,4 +1,4 @@
-"""Architecture registry: the ten assigned architectures + paper workloads.
+"""Architecture registry: the assigned architectures + paper workloads.
 
 Each ``configs/<arch>.py`` exports an ``ARCH: ArchSpec`` with the exact
 published configuration, a reduced same-family smoke config, and serving
@@ -61,7 +61,7 @@ class ArchSpec:
 _ARCH_IDS = [
     "internvl2_1b", "granite_8b", "llama32_3b", "qwen15_110b", "glm4_9b",
     "arctic_480b", "olmoe_1b_7b", "recurrentgemma_9b", "xlstm_350m",
-    "seamless_m4t_large_v2",
+    "seamless_m4t_large_v2", "mellum2_12b",
 ]
 
 ALIASES = {i.replace("_", "-"): i for i in _ARCH_IDS}

@@ -293,6 +293,42 @@ def aimc_apply_stacked(stack: AimcLinearState, x: jnp.ndarray, cfg: AimcConfig,
     return y.reshape(g_, *lead, stack.n)
 
 
+def aimc_apply_experts(states, x: jnp.ndarray, layer, block_expert, n_used,
+                       cfg: AimcConfig, *, block_rows: int) -> jnp.ndarray:
+    """CM_QUEUE + CM_PROCESS + CM_DEQUEUE for a sparse-expert layer.
+
+    ``states``: G programmed expert stacks ``[L, E, KB, M, Np]`` of one
+    shape, read in place at ``layer``. ``x`` [R, K]: rows sorted by expert,
+    each expert's rows in whole blocks of ``block_rows`` (block i belongs
+    to expert ``block_expert[i]``; the first ``n_used`` blocks hold rows,
+    padding rows are zero). Each expert quantises its rows under one DAC
+    scale, the max-abs over them, as a call of `aimc_apply` over just those
+    rows would. Returns [G, R, N]. Serving applies programmed states without
+    a key, so no read noise is drawn here."""
+    first = states[0]
+    kb, m, _ = first.w_q.shape[-3:]
+    e = first.w_q.shape[-4]
+    r, k = x.shape
+    if k != first.k:
+        raise ValueError(f"in_features mismatch: {k} != {first.k}")
+    xf = x.astype(jnp.float32)
+    if k != kb * m:
+        xf = jnp.pad(xf, ((0, 0), (0, kb * m - k)))
+    if cfg.input_scale > 0.0:
+        s_x = jnp.full((e,), cfg.input_scale, jnp.float32)
+    else:
+        row_expert = jnp.repeat(jnp.asarray(block_expert, jnp.int32),
+                                block_rows)
+        amax = jnp.zeros((e,), jnp.float32).at[row_expert].max(
+            jnp.max(jnp.abs(xf), axis=1))
+        s_x = jnp.maximum(amax, 1e-12) * (1.0 / QMAX)
+    y = kernel_ops.aimc_matmul_experts(
+        xf, [st.w_q for st in states], [st.s_w for st in states], s_x,
+        layer, block_expert, n_used, adc_step=cfg.adc_step,
+        block_rows=block_rows, impl=cfg.impl)
+    return y[:, :, :first.n].astype(jnp.dtype(cfg.out_dtype))
+
+
 # ---------------------------------------------------------------------------
 # Noise-aware training: straight-through estimator.
 # ---------------------------------------------------------------------------

@@ -27,6 +27,16 @@ three leaks v1 still had around the fused MAC:
     epilogue. Slice g draws noise under `cprng.stack_seed(seed, g)`, so the
     stack is bit-equal to per-gate v2 calls.
 
+The grouped expert kernel (`aimc_expert_pallas`) serves a sparse-expert
+layer whose `[L, E, KB, M, Np]` expert stacks stay where they were
+programmed: input rows arrive sorted by expert, each expert's rows padded to
+whole row blocks, and scalar-prefetched ids (the layer, each row block's
+expert, the number of blocks in use) select every block's codes and scales
+straight from the stacks, so nothing slices a layer or an expert out of
+them. One call runs G same-shape expert matrices that share an input (the
+gate and up projections), each row block through the same DAC -> int8 MAC
+-> ADC steps as v2 under its expert's DAC scale.
+
 Grid: (B/bB, Np/bN, KB) — (G, B/bB, Np/bN, KB) stacked — with the row-block
 dimension innermost so the f32 output block [bB, bN] is revisited
 consecutively and accumulated in place. The int8 weight row-block panel
@@ -443,3 +453,91 @@ def aimc_matmul_pallas_stacked(
         out_shape=jax.ShapeDtypeStruct((g_, b, np_), jnp.float32),
         interpret=interpret,
     )(seed, offsets, *operands)
+
+
+# ---------------------------------------------------------------------------
+# grouped expert kernel — expert stacks read in place
+# ---------------------------------------------------------------------------
+
+
+def _aimc_expert_kernel(layer_ref, expert_ref, used_ref, x_ref, *refs,
+                        adc_step: float, n_gates: int, kb: int, m: int):
+    """One (column block, row block) cell: every gate's [bm, bN] output of
+    the row block's expert, or zeros past the blocks in use."""
+    w_refs, sw_refs = refs[:n_gates], refs[n_gates:2 * n_gates]
+    sx_ref, o_ref = refs[2 * n_gates], refs[-1]
+    i = pl.program_id(1)
+
+    @pl.when(i < used_ref[0])
+    def _compute():
+        s_x = sx_ref[0, 0, 0]
+        for g in range(n_gates):
+            acc = None
+            for k in range(kb):
+                contrib = _mac_adc_contrib(
+                    x_ref[:, k * m:(k + 1) * m], w_refs[g][0, 0, k],
+                    sw_refs[g][0, 0, pl.ds(k, 1), :], s_x, None, adc_step)
+                acc = contrib if acc is None else acc + contrib
+            o_ref[g] = acc
+
+    # idle blocks repeat the last used expert's id: their rows come out zero
+    # so that they leave its DAC scale in the next call as it is
+    @pl.when(i >= used_ref[0])
+    def _idle():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("adc_step", "block_rows", "block_n",
+                              "interpret"))
+def aimc_expert_pallas(x, w_qs, s_ws, s_x, layer, block_expert, n_used, *,
+                       adc_step: float, block_rows: int, block_n: int = 512,
+                       interpret: bool = False):
+    """Grouped crossbar MVM over expert stacks read in place.
+
+    x          f32 [R, KB*M]   rows sorted by expert, each expert's rows
+                               padded to whole blocks of ``block_rows``
+    w_qs       G int8 stacks [L, E, KB, M, Np] (same shape)
+    s_ws       G f32 stacks [L, E, KB, Np]
+    s_x        f32 [E] the DAC scale of each expert's rows
+    layer      int32 [1] the layer the stacks are read at
+    block_expert int32 [R / block_rows] the expert of each row block
+    n_used     int32 [1] the row blocks that hold rows; later blocks come
+               out zero, and their expert should repeat the last used one
+               so that no codes are fetched for them
+    -> f32 [G, R, Np].
+
+    Grid (Np/bN, R/bm): for one column block the row blocks run in order,
+    so consecutive blocks of one expert keep its [KB, M, bN] panel of
+    codes in VMEM and each touched expert's codes are read once."""
+    g_ = len(w_qs)
+    r, kp = x.shape
+    _, e, kb, m, np_ = w_qs[0].shape
+    bm, bn = block_rows, block_n
+    if r % bm or np_ % bn or kp != kb * m:
+        raise ValueError(f"x {x.shape} / Np={np_} do not fit blocks "
+                         f"({bm}, {bn}) of {kb}x{m} rows")
+    grid = (np_ // bn, r // bm)
+    w_spec = pl.BlockSpec((1, 1, kb, m, bn),
+                          lambda j, i, lyr, be, nu: (lyr[0], be[i], 0, 0, j))
+    sw_spec = pl.BlockSpec((1, 1, kb, bn),
+                           lambda j, i, lyr, be, nu: (lyr[0], be[i], 0, j))
+    in_specs = ([pl.BlockSpec((bm, kp), lambda j, i, lyr, be, nu: (i, 0))]
+                + [w_spec] * g_ + [sw_spec] * g_
+                + [pl.BlockSpec((1, 1, 1),
+                                lambda j, i, lyr, be, nu: (be[i], 0, 0))])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=grid, in_specs=in_specs,
+        out_specs=pl.BlockSpec((g_, bm, bn),
+                               lambda j, i, lyr, be, nu: (0, i, j)))
+    kernel = functools.partial(_aimc_expert_kernel, adc_step=float(adc_step),
+                               n_gates=g_, kb=kb, m=m)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((g_, r, np_), jnp.float32),
+        interpret=interpret, name="aimc_expert_pallas",
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.asarray(block_expert, jnp.int32),
+      jnp.asarray(n_used, jnp.int32).reshape(1),
+      x.astype(jnp.float32), *w_qs, *s_ws,
+      s_x.reshape(e, 1, 1).astype(jnp.float32))

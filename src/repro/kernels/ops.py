@@ -2,10 +2,11 @@
 
 ``aimc_matmul_v2`` is the execution-path entry point used by ``core.aimc``:
 in-kernel PRNG read noise (scalar seed instead of a streamed `[KB, B, Np]`
-tensor), fused bias/activation epilogue, and `aimc_matmul_stacked` for
-gate-fused multi-MVM stacks. Each picks its implementation here and only
-here (`resolve_impl`): ``impl="auto"`` (the default) runs the compiled
-Pallas kernel on a TPU backend and the pure-jnp oracle elsewhere. The
+tensor), fused bias/activation epilogue, `aimc_matmul_stacked` for
+gate-fused multi-MVM stacks, and `aimc_matmul_experts` for a sparse-expert
+layer's grouped call over its expert stacks. Each picks its implementation
+here and only here (`resolve_impl`): ``impl="auto"`` (the default) runs the
+compiled Pallas kernel on a TPU backend and the pure-jnp oracle elsewhere. The
 oracle stays selectable as ``"ref"`` (health probes, tests) and the
 kernel in the Pallas interpreter as ``"pallas_interpret"`` (kernel tests).
 A kernel that fails to lower raises; nothing falls back to another impl.
@@ -32,7 +33,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.compat import current_mesh
 from repro.kernels import ref as _ref
-from repro.kernels.aimc_mvm import (EPILOGUES, aimc_matmul_pallas,
+from repro.kernels.aimc_mvm import (EPILOGUES, aimc_expert_pallas,
+                                    aimc_matmul_pallas,
                                     aimc_matmul_pallas_stacked,
                                     aimc_matmul_pallas_v2)
 from repro.kernels.ref import EPILOGUE_FNS  # re-export: unfused fallbacks
@@ -238,3 +240,22 @@ def aimc_matmul_stacked(x, w_q, s_w, s_x, seed=None, bias=None, *,
         return y[:, :b]
 
     return _map_over_mesh(call, x, w_q, s_w, s_x, seed, bias)
+
+
+def aimc_matmul_experts(x, w_qs, s_ws, s_x, layer, block_expert, n_used, *,
+                        adc_step: float, block_rows: int, impl: str = "auto"):
+    """Grouped expert MVM over `[L, E, KB, M, Np]` stacks read in place
+    (`aimc_mvm.aimc_expert_pallas`; contract there): ``x`` [R, KB*M] holds
+    rows sorted by expert in whole blocks of ``block_rows``. Returns f32
+    [G, R, Np] for the G stacks in ``w_qs``. A kernel block spans all Np
+    bit lines: an expert's whole [KB, M, Np] panel of codes is one fetch."""
+    impl = resolve_impl(impl)
+    w_qs, s_ws = tuple(w_qs), tuple(s_ws)
+    if impl == "ref":
+        return _ref.aimc_expert_ref(x, w_qs, s_ws, s_x, layer, block_expert,
+                                    n_used, adc_step=adc_step,
+                                    block_rows=block_rows)
+    return aimc_expert_pallas(
+        x, w_qs, s_ws, s_x, layer, block_expert, n_used, adc_step=adc_step,
+        block_rows=block_rows, block_n=w_qs[0].shape[-1],
+        interpret=(impl == "pallas_interpret"))

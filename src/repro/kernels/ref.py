@@ -101,6 +101,35 @@ def aimc_matmul_stacked_ref(x, w_q, s_w, s_x, seed=None, bias=None, *,
     return jnp.stack(outs)
 
 
+def aimc_expert_ref(x, w_qs, s_ws, s_x, layer, block_expert, n_used, *,
+                    adc_step: float, block_rows: int) -> jnp.ndarray:
+    """Grouped expert oracle (`aimc_mvm.aimc_expert_pallas`): row block i
+    of ``x`` [R, KB*M] runs through expert ``block_expert[i]`` of each
+    stack ``w_qs[g]`` [L, E, KB, M, Np] at ``layer``, quantised under that
+    expert's DAC scale ``s_x[e]``; blocks from ``n_used`` on come out zero.
+    Returns f32 [G, R, Np]."""
+    r, _ = x.shape
+    nb = r // block_rows
+    blocks = jnp.asarray(block_expert, jnp.int32)
+    row_expert = jnp.repeat(blocks, block_rows)                     # [R]
+    live = (jnp.arange(nb) < jnp.asarray(n_used).reshape(()))
+    live = jnp.repeat(live, block_rows)[:, None]
+    outs = []
+    for w_q, s_w in zip(w_qs, s_ws):
+        _, _, kb, m, np_ = w_q.shape
+        w = jnp.take(w_q[layer], blocks, axis=0)              # [nb, KB, M, Np]
+        sw = jnp.take(s_w[layer], blocks, axis=0)             # [nb, KB, Np]
+        sx = jnp.take(s_x, row_expert).reshape(nb, block_rows, 1, 1)
+        xb = x.reshape(nb, block_rows, kb, m).astype(jnp.float32)
+        x_q = jnp.clip(jnp.round(xb / sx), -127, 127).astype(jnp.int32)
+        acc = jnp.einsum("ibkm,ikmn->ikbn", x_q, w.astype(jnp.int32))
+        codes = adc_quantize(acc, jnp.float32(adc_step)).astype(jnp.float32)
+        y = jnp.sum(codes * sw[:, :, None, :], axis=1)        # [nb, bm, Np]
+        y = y * (jnp.float32(adc_step) * sx[:, :, 0])
+        outs.append(jnp.where(live, y.reshape(r, np_), 0.0))
+    return jnp.stack(outs)
+
+
 def digital_matmul_ref(x, w, out_dtype=jnp.float32):
     """The digital (CPU/SIMD) baseline the paper compares against: a plain
     full-precision matmul."""

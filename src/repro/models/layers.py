@@ -25,10 +25,12 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.aimc import (AimcConfig, AimcLinearState, aimc_apply,
                              aimc_apply_stacked, aimc_linear_ste)
@@ -263,17 +265,60 @@ def layernorm(x, scale, bias, eps: float = 1e-5):
     return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0):
-    """Rotary embedding. x: [B, S, H, D] (D even), positions: [B, S]."""
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0,
+         inv_freq=None, scale=None):
+    """Rotary embedding. x: [B, S, H, D] (D even), positions: [B, S].
+
+    ``inv_freq`` ([D/2], a layer's table from `rope_params`) replaces the
+    default frequencies ``theta ** (-i / (D/2))``; ``scale`` multiplies cos
+    and sin (YaRN's attention factor)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freqs = (1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+             if inv_freq is None else inv_freq)
     angles = positions[..., None].astype(jnp.float32) * freqs      # [B, S, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+def rope_params(head_dim: int, params: dict) -> tuple[np.ndarray, float]:
+    """(inverse frequencies [head_dim/2], cos/sin scale) of one rope
+    section as a HuggingFace ``rope_parameters`` entry states it:
+    ``rope_type`` "default" (``rope_theta``) or "yarn" (``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``; YaRN's ramp between interpolated and extrapolated
+    frequencies, correction range truncated to whole dims)."""
+    base = float(params["rope_theta"])
+    pos = base ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    kind = params.get("rope_type", "default")
+    if kind == "default":
+        return (1.0 / pos).astype(np.float32), 1.0
+    if kind != "yarn":
+        raise ValueError(f"unknown rope_type {kind!r}")
+    factor = float(params["factor"])
+    orig = float(params["original_max_position_embeddings"])
+
+    def dim_of(rot):        # the dim whose wavelength fits `rot` turns
+        return (head_dim * math.log(orig / (rot * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(dim_of(float(params.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(dim_of(float(params.get("beta_slow", 1)))),
+               head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2) - low) / (high - low), 0, 1)
+    extra = 1.0 - ramp               # share of the extrapolated frequency
+    inv = (1.0 / (factor * pos)) * (1.0 - extra) + (1.0 / pos) * extra
+    scale = params.get("attention_factor")
+    if scale is None:
+        scale = 0.1 * math.log(factor) + 1.0
+    return inv.astype(np.float32), float(scale)
 
 
 # ---------------------------------------------------------------------------

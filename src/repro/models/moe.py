@@ -1,7 +1,15 @@
 """Mixture-of-Experts FFN (arctic-480b: 128e top-2 + dense residual;
-olmoe-1b-7b: 64e top-8).
+olmoe-1b-7b: 64e top-8; mellum2-12b: 64e top-8).
 
-Two capacity-bucketed dispatch strategies, both deterministic-shape and
+Serving (`moe_serve`) routes dropless: every token goes through all of its
+top-k experts, whatever its batch mates chose, so a request's output does
+not depend on what it is batched with. The routed rows are sorted by
+expert; programmed expert stacks then run as ONE grouped crossbar call per
+projection group (`core.aimc.aimc_apply_experts`, reading the stacked codes
+in place at the layer index), and raw expert weights as a ragged matmul.
+
+Training (`moe_ffn`) keeps capacity dispatch: two capacity-bucketed
+dispatch strategies, both deterministic-shape and
 dry-run friendly, experts sharded over the `model` mesh axis:
 
   * ``sort``   (default; §Perf iteration 2) — argsort tokens by expert,
@@ -27,8 +35,10 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.core.aimc import AimcLinearState
-from repro.models.layers import Execution, as_weight, linear, shard_act
+from repro import tracing
+from repro.core.aimc import AimcLinearState, aimc_apply_experts
+from repro.models.layers import (Execution, _current_mesh, as_weight, linear,
+                                 shard_act)
 
 
 def moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
@@ -42,10 +52,7 @@ def moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     cap = max(1, int(t * top_k / e * capacity_factor))
 
     # ---- router (digital) --------------------------------------------------
-    logits = (x.astype(jnp.float32) @ router_w.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                        # [T, E]
-    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)              # [T, k]
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    gate_vals, gate_idx, probs = route(x, router_w, top_k)
 
     # load-balance aux (computed before capacity truncation)
     me = jnp.mean(probs, axis=0)
@@ -100,6 +107,111 @@ def moe_ffn(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         y = jnp.einsum("tk,tkd->td", gate_vals.astype(exe.cdtype),
                        ye_flat[slot_o])
     return y.astype(exe.cdtype), aux
+
+
+def route(x, router_w, top_k: int):
+    """Float32 softmax router: (gates [T, k] renormalised over the top k,
+    expert ids [T, k], probabilities [T, E])."""
+    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, gate_idx = jax.lax.top_k(probs, top_k)
+    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    return gate_vals, gate_idx, probs
+
+
+def serves_dropless(w_gate, exe: Execution, mesh=None) -> bool:
+    """Whether serving routes these experts dropless (`moe_serve`).
+
+    Programmed stacks always do. Raw weights do in digital mode, except
+    where a `model` axis of ``mesh`` (default: the active one) shards them:
+    `moe_serve` has no expert-parallel layout, so there they keep
+    `moe_ffn`'s expert-parallel capacity dispatch (`_moe_sharded`). Raw
+    weights in AIMC mode run `moe_ffn`'s per-expert crossbar emulation."""
+    if isinstance(w_gate, AimcLinearState):
+        return True
+    if exe.mode == "aimc":
+        return False
+    mesh = mesh if mesh is not None else _current_mesh()
+    return mesh is None or dict(mesh.shape).get("model", 1) == 1
+
+
+def expert_block_rows(rows: int, n_experts: int) -> int:
+    """Rows per block of the grouped layout: the mean group size rounded up
+    to the 8-row sublane tile, at most 128 (8 in a decode step of 32 lanes
+    x top-8 over 64 experts, 128 in a prefill of 1536 rows)."""
+    mean = -(-rows // n_experts)
+    return min(128, -(-mean // 8) * 8)
+
+
+def moe_serve(x, router_w, w_gate, w_up, w_down, *, top_k: int,
+              exe: Execution, layer=None):
+    """Dropless expert FFN for serving. x: [T, d] -> ([T, d], touched).
+
+    Programmed experts (`AimcLinearState` stacks ``[L, E, ...]``) are read
+    in place at ``layer``; raw expert weights arrive as this layer's
+    ``[E, d, ff]`` / ``[E, ff, d]``. ``touched`` (int32) counts the experts
+    that received at least one row."""
+    t, d = x.shape
+    with jax.named_scope(tracing.MOE_ROUTER):
+        gate_vals, gate_idx, _ = route(x, router_w, top_k)
+    programmed = isinstance(w_gate, AimcLinearState)
+    e = w_gate.w_q.shape[-4] if programmed else w_gate.shape[0]
+    n = t * top_k
+    with jax.named_scope(tracing.MOE_DISPATCH):
+        ids = gate_idx.reshape(-1)                               # [T*k]
+        order = jnp.argsort(ids, stable=True)                    # by expert
+        sizes = jnp.zeros((e,), jnp.int32).at[ids].add(1)
+        touched = jnp.sum(sizes > 0).astype(jnp.int32)
+        xs = x[order // top_k]                                   # sorted rows
+    if programmed:
+        y_pad, dest = _grouped_crossbar(xs, ids[order], sizes, w_gate, w_up,
+                                        w_down, layer, exe)
+    else:
+        with jax.named_scope(tracing.EXPERTS):
+            cd = exe.cdtype
+            g = jax.lax.ragged_dot(xs.astype(cd), as_weight(w_gate, cd),
+                                   sizes)
+            u = jax.lax.ragged_dot(xs.astype(cd), as_weight(w_up, cd), sizes)
+            y_sorted = jax.lax.ragged_dot(jax.nn.silu(g) * u,
+                                          as_weight(w_down, cd), sizes)
+    with jax.named_scope(tracing.MOE_COMBINE):
+        if programmed:
+            y_sorted = y_pad[dest]
+        y_rows = jnp.zeros((n, d), y_sorted.dtype).at[order].set(y_sorted)
+        y = jnp.einsum("tk,tkd->td", gate_vals.astype(jnp.float32),
+                       y_rows.reshape(t, top_k, d).astype(jnp.float32))
+    return y.astype(exe.cdtype), touched
+
+
+def _grouped_crossbar(xs, sorted_ids, sizes, w_gate, w_up, w_down, layer,
+                      exe: Execution):
+    """The sorted rows [n, d] through their experts' programmed gate/up and
+    down stacks: each expert's rows padded to whole blocks, one grouped
+    kernel call for gate+up (shared input and DAC scale), one for down.
+    Returns the padded layout's outputs and each sorted row's place in it."""
+    n, d = xs.shape
+    e = sizes.shape[0]
+    bm = expert_block_rows(n, e)
+    with jax.named_scope(tracing.MOE_DISPATCH):
+        padded = -(-sizes // bm) * bm
+        ends = jnp.cumsum(padded)
+        starts = ends - padded
+        rank = jnp.arange(n) - (jnp.cumsum(sizes) - sizes)[sorted_ids]
+        dest = starts[sorted_ids] + rank                         # [n]
+        nb = -(-(n + e * (bm - 1)) // bm)
+        x_pad = jnp.zeros((nb * bm, d), xs.dtype).at[dest].set(xs)
+        n_used = ends[-1] // bm
+        blocks = jnp.searchsorted(ends, jnp.arange(nb) * bm, side="right")
+        last = blocks[jnp.maximum(n_used - 1, 0)]
+        blocks = jnp.where(jnp.arange(nb) < n_used, blocks, last)
+        blocks = blocks.astype(jnp.int32)
+    with jax.named_scope(tracing.EXPERTS):
+        g, u = aimc_apply_experts((w_gate, w_up), x_pad, layer, blocks,
+                                  n_used, exe.aimc, block_rows=bm)
+        h = jax.nn.silu(g) * u
+        (y,) = aimc_apply_experts((w_down,), h, layer, blocks, n_used,
+                                  exe.aimc, block_rows=bm)
+    return y, dest
 
 
 def _moe_sharded(xd, gate_idx, gate_vals, w_gate, w_up, w_down,

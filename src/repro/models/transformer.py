@@ -24,6 +24,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import tracing
 from repro.core.aimc import AimcLinearState, stack_states
@@ -31,7 +32,7 @@ from repro.models import moe as moe_lib
 from repro.models.layers import (Execution, as_weight, decode_attention,
                                  dense_init, embed_init, flash_attention,
                                  linear, linear_stack, rmsnorm, rope,
-                                 shard_act, swiglu)
+                                 rope_params, shard_act, swiglu)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,6 +60,27 @@ class TransformerConfig:
     # attention chunking
     q_chunk: int = 1024
     kv_chunk: int = 1024
+    # per-layer attention kinds, as HuggingFace's ``layer_types`` names them
+    # ("sliding_attention" over the last ``sliding_window`` positions,
+    # "full_attention"; the first n_layers entries apply), with one
+    # ``rope_parameters`` section per kind (`layers.rope_params`). Empty:
+    # every layer is full attention with the default rope at rope_theta.
+    layer_types: tuple = ()
+    sliding_window: int = 0
+    rope_parameters: tuple = ()       # ((kind, ((key, value), ...)), ...)
+
+    def __post_init__(self):
+        # published lists and dicts arrive from JSON: keep the config
+        # hashable (it is a static argument of jitted closures)
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        rp = self.rope_parameters
+        if isinstance(rp, dict):
+            rp = tuple((kind, tuple(sorted(sec.items())))
+                       for kind, sec in sorted(rp.items()))
+        object.__setattr__(self, "rope_parameters", tuple(rp))
+        if self.layer_types and len(self.layer_types) < self.n_layers:
+            raise ValueError(f"{len(self.layer_types)} layer_types for "
+                             f"{self.n_layers} layers")
 
     @property
     def hd(self) -> int:
@@ -67,6 +89,50 @@ class TransformerConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+
+# a full-attention layer's window: no position is that far back
+NO_WINDOW = 1 << 30
+# the expert stacks a serving closure reads in place (not scanned)
+EXPERT_STACKS = ("we_gate", "we_up", "we_down")
+
+
+def layer_data(cfg: TransformerConfig) -> dict:
+    """Per-layer data the layer scans carry beside the stacked weights, so
+    one scan body serves every layer kind: the layer's index (MoE: the
+    programmed expert stacks are read in place at it) and, where the
+    config has ``layer_types``, each layer's attention window and rope
+    table (inverse frequencies and cos/sin scale). Empty for a model with
+    neither, whose scans are then what they were without it."""
+    out = {}
+    if cfg.is_moe:
+        out["layer"] = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    if cfg.layer_types:
+        kinds = cfg.layer_types[:cfg.n_layers]
+        sections = {kind: dict(sec) for kind, sec in cfg.rope_parameters}
+        default = {"rope_type": "default", "rope_theta": cfg.rope_theta}
+        tables = [rope_params(cfg.hd, sections.get(kind, default))
+                  for kind in kinds]
+        for kind in kinds:
+            if kind not in ("sliding_attention", "full_attention"):
+                raise ValueError(f"unknown layer type {kind!r}")
+        if "sliding_attention" in kinds and cfg.sliding_window < 1:
+            raise ValueError("sliding_attention layers need sliding_window")
+        out["window"] = jnp.asarray(
+            [cfg.sliding_window if kind == "sliding_attention" else NO_WINDOW
+             for kind in kinds], jnp.int32)
+        out["inv_freq"] = jnp.asarray(np.stack([t[0] for t in tables]))
+        out["rope_scale"] = jnp.asarray([t[1] for t in tables], jnp.float32)
+    return out
+
+
+def _split_experts(blocks: dict):
+    """(scanned blocks, expert stacks read in place): programmed expert
+    stacks leave the scan's inputs, so the scan copies no layer slice of
+    them; raw expert weights stay scanned."""
+    inplace = {n: blocks[n] for n in EXPERT_STACKS
+               if isinstance(blocks.get(n), AimcLinearState)}
+    return {n: v for n, v in blocks.items() if n not in inplace}, inplace
 
 
 # ---------------------------------------------------------------------------
@@ -160,7 +226,9 @@ def fuse_gate_stacks(params):
     return dict(params, blocks=blocks)
 
 
-def _qkv(h, blk, cfg, exe, keys, positions):
+def _qkv(h, blk, cfg, exe, keys, positions, ld):
+    """q, k, v of one block; ``ld`` is the layer's `layer_data` (its rope
+    table, where the config has one)."""
     b, s, d = h.shape
     if "wqkv" in blk:      # gate-fused stack (fuse_gate_stacks, MHA)
         biases = (jnp.stack([blk["bq"], blk["bk"], blk["bv"]])
@@ -170,8 +238,11 @@ def _qkv(h, blk, cfg, exe, keys, positions):
         q = linear(h, blk["wq"], exe, keys[0], blk.get("bq"))
         k = linear(h, blk["wk"], exe, keys[1], blk.get("bk"))
         v = linear(h, blk["wv"], exe, keys[2], blk.get("bv"))
-    q = rope(q.reshape(b, s, cfg.n_heads, cfg.hd), positions, cfg.rope_theta)
-    k = rope(k.reshape(b, s, cfg.n_kv_heads, cfg.hd), positions, cfg.rope_theta)
+    table = (ld.get("inv_freq"), ld.get("rope_scale"))
+    q = rope(q.reshape(b, s, cfg.n_heads, cfg.hd), positions, cfg.rope_theta,
+             *table)
+    k = rope(k.reshape(b, s, cfg.n_kv_heads, cfg.hd), positions,
+             cfg.rope_theta, *table)
     v = v.reshape(b, s, cfg.n_kv_heads, cfg.hd)
     # Megatron-style TP: Q heads sharded over `model` (skipped when the head
     # count does not divide); GQA K/V usually have too few heads to shard.
@@ -186,7 +257,13 @@ def _qkv(h, blk, cfg, exe, keys, positions):
     return q, k, v
 
 
-def _ffn(h2, blk, cfg: TransformerConfig, exe: Execution, keys):
+def _ffn(h2, blk, cfg: TransformerConfig, exe: Execution, keys, *,
+         serve: bool = False, experts=None, layer=None):
+    """The block's FFN -> (out, aux). MoE: training routes with capacity
+    dispatch and ``aux`` is its load-balancing loss. ``serve`` routes
+    dropless where `moe.serves_dropless` says so (`moe.moe_serve`, with
+    ``experts`` read in place at ``layer`` where given) and ``aux`` counts
+    the experts touched; where capacity dispatch serves, ``aux`` is None."""
     if not cfg.is_moe:
         if "w_gu" in blk:  # gate-fused stack (fuse_gate_stacks)
             g, u = linear_stack(h2, blk["w_gu"], exe, keys[4])
@@ -197,10 +274,15 @@ def _ffn(h2, blk, cfg: TransformerConfig, exe: Execution, keys):
         return swiglu(h2, blk["w_gate"], blk["w_up"], blk["w_down"], exe,
                       keys[4]), 0.0
     b, s, d = h2.shape
-    y, aux = moe_lib.moe_ffn(
-        h2.reshape(b * s, d), blk["router"], blk["we_gate"], blk["we_up"],
-        blk["we_down"], top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-        exe=exe, key=keys[4])
+    w = [(experts or {}).get(n, blk.get(n)) for n in EXPERT_STACKS]
+    if serve and moe_lib.serves_dropless(w[0], exe):
+        y, aux = moe_lib.moe_serve(h2.reshape(b * s, d), blk["router"], *w,
+                                   top_k=cfg.top_k, exe=exe, layer=layer)
+    else:
+        y, aux = moe_lib.moe_ffn(
+            h2.reshape(b * s, d), blk["router"], *w, top_k=cfg.top_k,
+            capacity_factor=cfg.capacity_factor, exe=exe, key=keys[4])
+        aux = None if serve else aux
     y = y.reshape(b, s, d)
     if cfg.moe_dense_residual:
         y = y + swiglu(h2, blk["wd_gate"], blk["wd_up"], blk["wd_down"],
@@ -209,12 +291,13 @@ def _ffn(h2, blk, cfg: TransformerConfig, exe: Execution, keys):
 
 
 def block_forward(h, blk, cfg: TransformerConfig, exe: Execution, key,
-                  positions):
+                  positions, ld=None):
+    ld = ld or {}
     keys = list(jax.random.split(key, 6)) if key is not None else [None] * 6
     h = shard_act(h)
     q, k, v = _qkv(rmsnorm(h, blk["ln1"], cfg.norm_eps), blk, cfg, exe, keys,
-                   positions)
-    att = flash_attention(q, k, v, causal=True,
+                   positions, ld)
+    att = flash_attention(q, k, v, causal=True, window=ld.get("window"),
                           q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
     b, s, _ = h.shape
     h = h + linear(att.reshape(b, s, -1), blk["wo"], exe, keys[3])
@@ -255,13 +338,14 @@ def forward(params, tokens, cfg: TransformerConfig, exe: Execution = None,
     @jax.checkpoint
     def body(carry, xs):
         h, aux = carry
-        blk, lk = xs
+        blk, lk, ld = xs
         key = lk if rng is not None else None
-        h, a = block_forward(h, blk, cfg, exe, key, positions)
+        h, a = block_forward(h, blk, cfg, exe, key, positions, ld)
         return (h, aux + a), None
 
     (h, aux), _ = jax.lax.scan(body, (h, 0.0),
-                               (params["blocks"], layer_keys))
+                               (params["blocks"], layer_keys,
+                                layer_data(cfg)))
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     if return_hidden:
         return h, aux
@@ -310,16 +394,21 @@ def prefill(params, tokens, cfg: TransformerConfig, exe: Execution = None,
     h = embed_tokens(params, tokens, cfg, exe, patch_embeds)
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
 
+    blocks, experts = _split_experts(params["blocks"])
+
     @jax.named_scope(tracing.BLOCK)
-    def body(h, blk):
+    def body(h, xs):
+        blk, ld = xs
         keys = [None] * 6
         q, k, v = _qkv(rmsnorm(h, blk["ln1"], cfg.norm_eps), blk, cfg, exe,
-                       keys, positions)
+                       keys, positions, ld)
         with jax.named_scope(tracing.ATTENTION):
-            att = flash_attention(q, k, v, causal=True, q_chunk=cfg.q_chunk,
-                                  kv_chunk=cfg.kv_chunk)
+            att = flash_attention(q, k, v, causal=True,
+                                  window=ld.get("window"),
+                                  q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
         h = h + linear(att.reshape(b, s, -1), blk["wo"], exe, keys[3])
-        ff, _ = _ffn(rmsnorm(h, blk["ln2"], cfg.norm_eps), blk, cfg, exe, keys)
+        ff, _ = _ffn(rmsnorm(h, blk["ln2"], cfg.norm_eps), blk, cfg, exe, keys,
+                     serve=True, experts=experts, layer=ld.get("layer"))
         with jax.named_scope(tracing.KV_WRITE):
             kc = jnp.zeros((b, max_seq, cfg.n_kv_heads, cfg.hd), cache_dtype)
             vc = jnp.zeros((b, max_seq, cfg.n_kv_heads, cfg.hd), cache_dtype)
@@ -330,7 +419,7 @@ def prefill(params, tokens, cfg: TransformerConfig, exe: Execution = None,
         return h + ff, (kc, vc)
 
     with jax.named_scope(tracing.LAYERS):
-        h, (ks, vs) = jax.lax.scan(body, h, params["blocks"])
+        h, (ks, vs) = jax.lax.scan(body, h, (blocks, layer_data(cfg)))
     with jax.named_scope(tracing.HEAD):
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         unembed = (params["embed"].T if cfg.tie_embeddings
@@ -348,7 +437,8 @@ def prefill(params, tokens, cfg: TransformerConfig, exe: Execution = None,
 
 
 def decode_step(params, cache, tokens, cfg: TransformerConfig,
-                exe: Execution = None, ragged: bool = False, active=None):
+                exe: Execution = None, ragged: bool = False, active=None,
+                count_experts: bool = False):
     """tokens: [B, 1] one new token per sequence -> (logits [B,1,V], cache).
 
     ``ragged=False`` is the lockstep fast path (decode_32k/long_500k cells:
@@ -364,7 +454,11 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig,
     step never rewrites the whole cache. ``active`` ([B] bool) freezes the
     lanes it marks False: their rows are dropped and their ``len`` holds —
     bit for bit what a select of the old cache over those lanes gave. With
-    ``active=None`` every lane writes and advances."""
+    ``active=None`` every lane writes and advances.
+
+    ``count_experts`` (MoE served dropless, `moe.serves_dropless`) also
+    returns the experts each layer's step touched, summed over the layers
+    (int32): (logits, cache, touched)."""
     exe = exe or Execution()
     b = tokens.shape[0]
     h = jnp.take(params["embed"], tokens, axis=0).astype(exe.cdtype)
@@ -374,12 +468,14 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig,
     row_idx = jnp.clip(cache["len"], 0, max_seq - 1)               # [B]
     lanes = jnp.arange(b)
 
+    blocks, experts = _split_experts(params["blocks"])
+
     @jax.named_scope(tracing.BLOCK)
     def body(h, xs):
-        blk, kc, vc = xs
+        blk, kc, vc, ld = xs
         keys = [None] * 6
         q, k, v = _qkv(rmsnorm(h, blk["ln1"], cfg.norm_eps), blk, cfg, exe,
-                       keys, positions)
+                       keys, positions, ld)
         with jax.named_scope(tracing.KV_WRITE):
             if ragged:        # a row scatter, not a one-hot multiply
                 kc = kc.at[lanes, row_idx].set(k[:, 0].astype(kc.dtype))
@@ -390,14 +486,23 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig,
                 vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
                                                   (0, pos0, 0, 0))
         with jax.named_scope(tracing.ATTENTION):
-            att = decode_attention(q, kc, vc, kv_len=cache["len"] + 1)
+            att = decode_attention(q, kc, vc, kv_len=cache["len"] + 1,
+                                   window=ld.get("window"))
         h = h + linear(att.reshape(b, 1, -1), blk["wo"], exe, keys[3])
-        ff, _ = _ffn(rmsnorm(h, blk["ln2"], cfg.norm_eps), blk, cfg, exe, keys)
-        return h + ff, (k[:, 0], v[:, 0])
+        ff, touched = _ffn(rmsnorm(h, blk["ln2"], cfg.norm_eps), blk, cfg,
+                           exe, keys, serve=True, experts=experts,
+                           layer=ld.get("layer"))
+        rows = (k[:, 0], v[:, 0])
+        if not count_experts:
+            return h + ff, rows
+        if not cfg.is_moe or touched is None:
+            raise ValueError("count_experts: only dropless routing counts "
+                             "the experts it touches")
+        return h + ff, rows + (touched,)
 
     with jax.named_scope(tracing.LAYERS):
-        h, (ks, vs) = jax.lax.scan(body, h, (params["blocks"],
-                                             cache["k"], cache["v"]))
+        h, (ks, vs, *touched) = jax.lax.scan(
+            body, h, (blocks, cache["k"], cache["v"], layer_data(cfg)))
     with jax.named_scope(tracing.KV_WRITE):
         # the lockstep write clamps at the cap as dynamic_update_slice does
         idx = row_idx if ragged else jnp.broadcast_to(
@@ -413,6 +518,8 @@ def decode_step(params, cache, tokens, cfg: TransformerConfig,
         unembed = (params["embed"].T if cfg.tie_embeddings
                    else params["unembed"])
         logits = h.astype(jnp.float32) @ as_weight(unembed, jnp.float32)
+    if count_experts:
+        return logits, new_cache, jnp.sum(touched[0])
     return logits, new_cache
 
 
@@ -494,10 +601,10 @@ def prefill_chunk(params, tokens, cfg: TransformerConfig, exe: Execution,
     pids = jnp.where((j >= j0) & (j <= j1), pt_row, 0)
 
     def body(h, xs):
-        blk, kpl, vpl = xs
+        blk, kpl, vpl, ld = xs
         keys = [None] * 6
         q, k, v = _qkv(rmsnorm(h, blk["ln1"], cfg.norm_eps), blk, cfg, exe,
-                       keys, positions)
+                       keys, positions, ld)
         kc = kpl[pt_row].reshape(n_rows, hkv, hd)
         vc = vpl[pt_row].reshape(n_rows, hkv, hd)
         # extend by C rows so the slice write never clamps at the pool edge
@@ -509,14 +616,17 @@ def prefill_chunk(params, tokens, cfg: TransformerConfig, exe: Execution,
             vx, v[0].astype(vx.dtype), pos0, axis=0)
         att = flash_attention(q, kx[None, :context_len], vx[None, :context_len],
                               causal=True, q_offset=pos0,
+                              window=ld.get("window"),
                               q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
         h = h + linear(att.reshape(b, c, -1), blk["wo"], exe, keys[3])
-        ff, _ = _ffn(rmsnorm(h, blk["ln2"], cfg.norm_eps), blk, cfg, exe, keys)
+        ff, _ = _ffn(rmsnorm(h, blk["ln2"], cfg.norm_eps), blk, cfg, exe, keys,
+                     serve=True)
         kpl = kpl.at[pids].set(kx[:n_rows].reshape(m, page_size, hkv, hd))
         vpl = vpl.at[pids].set(vx[:n_rows].reshape(m, page_size, hkv, hd))
         return h + ff, (kpl, vpl)
 
-    h, (kp, vp) = jax.lax.scan(body, h, (params["blocks"], kp, vp))
+    h, (kp, vp) = jax.lax.scan(body, h, (params["blocks"], kp, vp,
+                                         layer_data(cfg)))
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     unembed = (params["embed"].T if cfg.tie_embeddings else params["unembed"])
     idx = jnp.broadcast_to(jnp.clip(span - 1, 0, c - 1), (b,))

@@ -98,6 +98,7 @@ import jax.numpy as jnp
 from repro import tracing
 from repro.models.layers import (Execution, at_stated_precision,
                                  mask_batch_select)
+from repro.models.moe import serves_dropless
 from repro.runtime.batcher import (Batcher, Request, RequestRecord,
                                    SlotAllocator, percentile)
 from repro.runtime.fault_tolerance import StragglerMonitor, resilient_step
@@ -174,6 +175,11 @@ class ServeReport:
     # bookkeeping, so the two can actually disagree if the engine double-
     # or under-counts (reconcile's job)
     observed_vectors: int = 0
+    # sparse-expert models: distinct experts that received rows, summed
+    # over every layer of every decode step (counted on the device, read
+    # back with each chunk; divide by n_steps x layers x experts for the
+    # share of the expert stacks a step reads)
+    experts_touched: int = 0
     wall_prefill_s: float = 0.0
     wall_decode_s: float = 0.0
     makespan_s: float = 0.0        # engine clock: last retirement - start
@@ -408,6 +414,13 @@ class ServeEngine:
             raise ValueError(f"paged KV serves the transformer module; "
                              f"got {module!r}")
         self._pt_width = -(-max_seq // page_size) if self._paged_kv else 0
+        # the dense decode closure of a dropless sparse-expert transformer
+        # reads back the experts each step touched
+        self._count_experts = (
+            module == "transformer" and not self._paged_kv
+            and getattr(cfg, "is_moe", False)
+            and serves_dropless(params["blocks"].get("we_gate"), exe,
+                                getattr(self, "mesh", None)))
         if self._use_legs:
             if family == "vlm":
                 raise ValueError(
@@ -416,8 +429,9 @@ class ServeEngine:
             if getattr(cfg, "is_moe", False):
                 raise ValueError(
                     "prefix_cache / prefill_chunk cannot serve MoE models: "
-                    "capacity-factor routing mixes positions, so a chunked "
-                    "prefill is not bit-equal to the dense one")
+                    "routing is dropless, but each expert's DAC scale is the "
+                    "max over the rows a call routes to it, so a prefill "
+                    "leg does not quantise as the whole prompt does")
             if cache_dtype != jnp.float32:
                 raise ValueError(
                     "prefix_cache / prefill_chunk require cache_dtype "
@@ -640,21 +654,25 @@ class ServeEngine:
 
         Returns (tok_buf, cache, state, ys) with per-step chunk outputs
         ys = (toks [length,S], active-at-entry [length,S], reason
-        [length,S]). The per-step busy count is NOT reduced on device:
-        `active.sum()` would be the only other cross-device collective in
-        the data-sharded loop (one all-reduce per token) — the host pops
-        it from the ``active`` rows it reads back anyway. ys rides outside
+        [length,S]), and for a sparse-expert transformer served dropless
+        (`moe.serves_dropless`) the experts each step touched over its
+        layers ([length]). The per-step busy count
+        is NOT reduced on device: `active.sum()` would be the only other
+        cross-device collective in the data-sharded loop (one all-reduce
+        per token) — the host pops it from the ``active`` rows it reads
+        back anyway. ys rides outside
         ``state`` because a subsequent insert donates the state buffers
         while a chunk's readback may still be pending (double-buffered
         serve)."""
         def one_step(carry, _):
             cache, tokens, st = carry
             active = st["active"]
+            touched = []
             if self.module == "transformer":
                 # frozen lanes' K/V rows are dropped in place, their len held
-                logits, cache = self.model.decode_step(
+                logits, cache, *touched = self.model.decode_step(
                     params, cache, tokens, self.cfg, self.exe, ragged=True,
-                    active=active)
+                    active=active, count_experts=self._count_experts)
             else:
                 logits, new_cache = self.model.decode_step(
                     params, cache, tokens, self.cfg, self.exe)
@@ -682,7 +700,7 @@ class ServeEngine:
             reason = jnp.where(active, reason, 0)
             new_st = {"active": active & (reason == 0), "gen": gen,
                       "pos": pos, "max_new": st["max_new"]}
-            return (cache, tok, new_st), (emitted, active, reason)
+            return (cache, tok, new_st), (emitted, active, reason, *touched)
 
         (cache, tok_buf, state), ys = jax.lax.scan(
             one_step, (cache, tok_buf, state), None, length=length)
@@ -1612,7 +1630,7 @@ class ServeEngine:
         dispatch) minus any prefill/insert wall already billed inside that
         window — the double-buffered loop admits WHILE a chunk flies."""
         with tracing.span("sync"):
-            toks, acts, reasons = jax.device_get(pend.ys)
+            toks, acts, reasons, *touched = jax.device_get(pend.ys)
         # any admission since the last sync has its prefill long done by
         # now (the chunk we just read back queued after it) — the deferred
         # first-token reads cost a host copy, not a wait
@@ -1632,6 +1650,8 @@ class ServeEngine:
             # the per-request records — reconcile compares two real countings
             report.observed_vectors += busy
             report.idle_vectors += self.n_slots * ran - busy
+            if touched:
+                report.experts_touched += int(touched[0].sum())
             self._step_no += ran
             # a chunk whose window held a hot reprogram is legitimately slow:
             # exempt it from the straggler EWMA (flagging recovery would page
@@ -1932,6 +1952,8 @@ class ShardedServeEngine(ServeEngine):
         ys_row = NamedSharding(mesh, fit_spec(
             P(None, dp), (self.decode_chunk, self.n_slots), mesh))
         ys_sh = (ys_row, ys_row, ys_row)
+        if self._count_experts:
+            ys_sh += (repl,)              # the experts touched per step
 
         tokens_s = jax.ShapeDtypeStruct((1, self.prompt_pad), jnp.int32)
         vl_s = jax.ShapeDtypeStruct((1,), jnp.int32)

@@ -23,6 +23,14 @@ and the compiled code is the same with or without them.
   lane_freeze  the engine's selects that keep frozen decode lanes unchanged:
                the emitted token, and the state of a recurrent model (a
                transformer's frozen K/V rows are dropped under kv_write)
+  moe_router   a sparse-expert layer's router: logits, softmax, top-k and
+               the renormalised gates
+  moe_dispatch sorting the routed rows by expert into the grouped layout
+  experts      the experts' projections (on the programmed path the
+               experts' DAC scales and the grouped crossbar kernel) and
+               their activation
+  moe_combine  gathering the experts' rows back to their tokens and the
+               gate-weighted sum
 """
 
 from __future__ import annotations
@@ -36,7 +44,13 @@ ATTENTION = "attention"
 KV_WRITE = "kv_write"
 HEAD = "head"
 LANE_FREEZE = "lane_freeze"
+MOE_ROUTER = "moe_router"
+MOE_DISPATCH = "moe_dispatch"
+EXPERTS = "experts"
+MOE_COMBINE = "moe_combine"
 SCOPES = (LAYERS, BLOCK, AIMC_MVM, ATTENTION, KV_WRITE, HEAD, LANE_FREEZE)
+# a sparse-expert block's parts, inside `block`
+MOE_SCOPES = (MOE_ROUTER, MOE_DISPATCH, EXPERTS, MOE_COMBINE)
 
 SPAN_PREFIX = "engine."
 

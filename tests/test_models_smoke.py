@@ -158,7 +158,7 @@ def _decode_match(spec, atol, s=12):
 
 
 @pytest.mark.parametrize("arch_id", ["granite_8b", "olmoe_1b_7b",
-                                     "internvl2_1b"])
+                                     "internvl2_1b", "mellum2_12b"])
 def test_transformer_decode_matches_forward(arch_id):
     _decode_match(get_arch(arch_id), atol=2e-3)
 
@@ -194,7 +194,7 @@ def test_shape_cells_defined():
                            "long_500k"}
     from repro.configs import all_cells
     cells = all_cells()
-    # 10 archs x 4 shapes - 8 long_500k skips (only rglru + xlstm run it)
-    assert len(cells) == 32
+    # 11 archs x 4 shapes - 9 long_500k skips (only rglru + xlstm run it)
+    assert len(cells) == 35
     longs = [a for (a, s) in cells if s == "long_500k"]
     assert sorted(longs) == ["recurrentgemma_9b", "xlstm_350m"]
